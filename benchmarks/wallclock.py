@@ -35,6 +35,7 @@ import numpy as np
 
 from repro.core import ecoflow, naive
 from repro.core.spec import ConvSpec, Epilogue, resolve_backend
+from repro.kernels.ops import interpret_mode
 
 
 def _time(fn, *args, iters=5, warmup=2):
@@ -548,7 +549,7 @@ def _plan_dict(op, spec, x_shape, dy_shape, epilogue=None):
     from repro.kernels import tiling
     strategy, plan = tiling.plan_strategy(
         op, spec, x_shape=x_shape, dy_shape=dy_shape,
-        interpret=jax.default_backend() != "tpu", epilogue=epilogue)
+        interpret=interpret_mode(), epilogue=epilogue)
     return {"cin_tile": plan.cin_tile, "cout_tile": plan.cout_tile,
             "spatial_tile": plan.spatial_tile,
             "tap_unroll": plan.tap_unroll,
@@ -629,7 +630,7 @@ def conv_backend_bench(iters=5, warmup=1, write_json=True, cases=None,
         x = jnp.asarray(rng.normal(size=(B, N, N, Ci)), jnp.float32)
         rec = {"layer": name, "error_map": O, "k": K, "stride": S,
                "c_in": Ci, "c_out": Co, "batch": B,
-               "interpret_mode": jax.default_backend() != "tpu",
+               "interpret_mode": interpret_mode(),
                "epilogue": "none",
                "tiling": {
                    "input_grad": _plan_dict("input_grad", spec,
@@ -697,7 +698,7 @@ def conv_backend_bench(iters=5, warmup=1, write_json=True, cases=None,
         zf = naive.dilated_forward_zero_mac_fraction(K, D)
         rec = {"layer": name, "n_in": N, "k": K, "stride": S,
                "dilation": D, "c_in": Ci, "c_out": Co, "batch": B,
-               "interpret_mode": jax.default_backend() != "tpu",
+               "interpret_mode": interpret_mode(),
                "zero_mac_fraction_naive": round(zf, 4),
                "epilogue": "none",
                "tiling": {
@@ -737,7 +738,7 @@ def conv_backend_bench(iters=5, warmup=1, write_json=True, cases=None,
         w = jnp.asarray(rng.normal(size=(K, K, Ci, Co)), jnp.float32)
         rec = {"layer": name, "error_map": O, "k": K, "stride": S,
                "dilation": D, "c_in": Ci, "c_out": Co, "batch": B,
-               "interpret_mode": jax.default_backend() != "tpu",
+               "interpret_mode": interpret_mode(),
                "epilogue": "none",
                "tiling": {
                    "input_grad": _plan_dict(
@@ -779,7 +780,7 @@ def conv_backend_bench(iters=5, warmup=1, write_json=True, cases=None,
         dy = jnp.asarray(rng.normal(size=(B, O, O, Co)), jnp.float32)
         rec = {"layer": name, "error_map": O, "k": K, "stride": S,
                "c_in": Ci, "c_out": Co, "batch": B,
-               "interpret_mode": jax.default_backend() != "tpu",
+               "interpret_mode": interpret_mode(),
                "epilogue": ep.tag,
                "tiling": {
                    "forward": _plan_dict("forward", spec, x.shape,
@@ -841,7 +842,7 @@ def conv_backend_bench(iters=5, warmup=1, write_json=True, cases=None,
         g = jnp.asarray(rng.normal(size=g_shape), jnp.float32)
         rec = {"layer": name, "error_map": O, "k": K, "stride": S,
                "c_in": Ci, "c_out": Co, "batch": B,
-               "interpret_mode": jax.default_backend() != "tpu",
+               "interpret_mode": interpret_mode(),
                "epilogue": ep.tag,
                "tiling": {
                    "input_grad": _plan_dict("input_grad", spec, g_shape,
@@ -896,7 +897,7 @@ def conv_backend_bench(iters=5, warmup=1, write_json=True, cases=None,
                                      if train_cases is None
                                      else train_cases):
         rec = {"layer": name, "kind": kind, "config": cfg,
-               "interpret_mode": jax.default_backend() != "tpu",
+               "interpret_mode": interpret_mode(),
                "epilogue": "fused" if fuse else "none",
                # per-layer geometries resolve through the planner's race
                "strategy": "auto",
@@ -923,7 +924,7 @@ def conv_backend_bench(iters=5, warmup=1, write_json=True, cases=None,
             rec = {"layer": f"{name}-d{n_dev}", "kind": kind,
                    "config": cfg, "n_devices": n_dev,
                    "mesh": list(MULTIDEV_MESHES[n_dev]),
-                   "interpret_mode": jax.default_backend() != "tpu",
+                   "interpret_mode": interpret_mode(),
                    "epilogue": "fused" if fuse else "none",
                    "strategy": "auto",
                    "train_step_us": {}}
@@ -949,7 +950,7 @@ def conv_backend_bench(iters=5, warmup=1, write_json=True, cases=None,
         from repro.serve.faults import FaultInjector, FaultSchedule
         s_iters = min(iters, _SERVE_MAX_ITERS)
         rec = {"layer": name, "kind": kind, "config": cfg,
-               "interpret_mode": jax.default_backend() != "tpu",
+               "interpret_mode": interpret_mode(),
                "epilogue": "fused", "strategy": "auto",
                "serve_us": {}, "serve_p99_us": {}, "serve_rps": {},
                "fault": {}}
@@ -1022,7 +1023,7 @@ def conv_backend_bench(iters=5, warmup=1, write_json=True, cases=None,
     for name, kind, cfg in flt(ELASTIC_TRAIN_CASES if elastic_cases
                                is None else elastic_cases):
         rec = {"layer": name, "kind": kind, "config": cfg,
-               "interpret_mode": jax.default_backend() != "tpu",
+               "interpret_mode": interpret_mode(),
                "epilogue": "fused", "strategy": "auto",
                "train_step_guard_us": {}, "recovery": {}}
         t_g = _time_interleaved(_guard_step_fns(kind, cfg),

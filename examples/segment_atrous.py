@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.models import vision
 from repro.optim.optimizer import AdamWConfig, adamw_init, adamw_update
+from repro.runtime import enable_compile_cache
 
 
 def synth_batch(step: int, *, batch=8, size=24):
@@ -56,6 +57,7 @@ def main():
                     help="run the branch relu tails as separate XLA ops "
                          "instead of the fused epilogue slot")
     args = ap.parse_args()
+    enable_compile_cache()
 
     rates = (1, 2, 4)
     params = vision.atrous_head_init(jax.random.PRNGKey(0), in_ch=3,

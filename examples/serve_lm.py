@@ -15,6 +15,7 @@ import numpy as np
 
 from repro.configs import get_smoke_config
 from repro.models.lm import LM
+from repro.runtime import enable_compile_cache
 from repro.serve.engine import Request, ServeEngine
 
 
@@ -25,6 +26,7 @@ def main():
     ap.add_argument("--requests", type=int, default=10)
     ap.add_argument("--max-new", type=int, default=12)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch)
     lm = LM(cfg)

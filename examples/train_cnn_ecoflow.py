@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.models import cnn
 from repro.optim.optimizer import AdamWConfig, adamw_init, adamw_update
+from repro.runtime import enable_compile_cache
 
 
 def synth_batch(step: int, *, batch=32, size=24, n_classes=10):
@@ -44,6 +45,7 @@ def main():
                     choices=("reference", "xla_zero_free", "pallas"),
                     help="conv dispatch backend (repro.core.spec)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     params = cnn.simple_cnn_init(jax.random.PRNGKey(0),
                                  widths=(16, 32, 64), n_classes=10)

@@ -17,6 +17,7 @@ import numpy as np
 
 from repro.models import gan
 from repro.optim.optimizer import AdamWConfig, adamw_init, adamw_update
+from repro.runtime import enable_compile_cache
 
 
 def real_batch(step, *, batch=16, size=32):
@@ -40,6 +41,7 @@ def main():
                     choices=("reference", "xla_zero_free", "pallas"),
                     help="conv dispatch backend (repro.core.spec)")
     args = ap.parse_args()
+    enable_compile_cache()
     Z, BASE, B = 32, 16, 16
 
     gp = gan.generator_init(jax.random.PRNGKey(0), z_dim=Z, base=BASE)

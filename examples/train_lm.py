@@ -18,6 +18,7 @@ from repro.configs import get_smoke_config
 from repro.data.pipeline import TokenDataset
 from repro.launch.mesh import make_debug_mesh
 from repro.optim.optimizer import AdamWConfig
+from repro.runtime import enable_compile_cache
 from repro.train.trainer import Trainer, TrainerConfig
 
 
@@ -28,6 +29,7 @@ def main():
     ap.add_argument("--fail-at", type=int, default=None,
                     help="inject a failure at this step, then restart")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_smoke_config(args.arch)
     mesh = make_debug_mesh()

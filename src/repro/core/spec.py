@@ -761,7 +761,7 @@ def sharded_backend(base: ConvBackend, mesh) -> ConvBackend:
     Each axis is applied only when it divides the corresponding global
     dim (same guard policy as `parallel.sharding._guard`); when neither
     axis applies the base backend runs replicated with no shard_map.
-    `check_rep=False` because pallas_call has no replication rule.  The
+    `check_vma=False` because pallas_call has no replication rule.  The
     base backend's methods run INSIDE the shard_map body, so its
     fused-vs-two-launch fallback and `tiling.plan_tiles` both see LOCAL
     shapes -- one forward and one backward pallas_call per shard."""
@@ -771,7 +771,6 @@ def sharded_backend(base: ConvBackend, mesh) -> ConvBackend:
         return hit
 
     import jax
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.parallel import sharding as _sh
@@ -787,8 +786,8 @@ def sharded_backend(base: ConvBackend, mesh) -> ConvBackend:
         return axes if n > 1 and dim % n == 0 else None
 
     def _launch(body, in_specs, out_specs, *args):
-        return shard_map(body, mesh=mesh, in_specs=in_specs,
-                         out_specs=out_specs, check_rep=False)(*args)
+        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                             out_specs=out_specs, check_vma=False)(*args)
 
     def _psum(v, axes):
         return jax.lax.psum(v, axes) if axes is not None else v

@@ -83,7 +83,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
 def flash_attention_pallas(q: jax.Array, k: jax.Array, v: jax.Array, *,
                            causal: bool = True, blk_q: int = 128,
                            blk_k: int = 128, q_offset: int | None = None,
-                           interpret: bool = True) -> jax.Array:
+                           interpret: bool) -> jax.Array:
     """q (B,Sq,Hq,D), k/v (B,Sk,Hk,D), Hq % Hk == 0 -> (B,Sq,Hq,D)."""
     B, Sq, Hq, D = q.shape
     _, Sk, Hk, _ = k.shape

@@ -79,7 +79,8 @@ from jax.experimental import pallas as pl
 
 from repro.core.spec import ConvSpec, _pair
 from repro.kernels import tiling
-from repro.kernels.tap_gather import gather_tap, pad_to_tap_windows
+from repro.kernels.tap_gather import (gather_tap, pad_to_tap_windows,
+                                      read_window, split_index)
 from repro.kernels.tconv_phase import (assemble_phase_major,
                                        pack_phase_filters)
 
@@ -87,6 +88,10 @@ from repro.kernels.tconv_phase import (assemble_phase_major,
 # ---------------------------------------------------------------------------
 # direct-conv VJP: (dx, dW) from one dy residency
 # ---------------------------------------------------------------------------
+
+def _clamp(v, hi: int):
+    return min(v, hi) if isinstance(v, int) else jnp.minimum(v, hi)
+
 
 def _bwd_kernel(dy_ref, w_ref, x_ref, *refs, tpw: int, kp: int,
                 kq: int, kh: int, kwf: int, per_h: int, per_w: int, sh: int,
@@ -103,29 +108,37 @@ def _bwd_kernel(dy_ref, w_ref, x_ref, *refs, tpw: int, kp: int,
     dx_ref, dw_ref = refs[1 if has_y else 0], refs[2 if has_y else 1]
     db_ref = refs[-1] if has_db else None
     b = pl.program_id(1)
-    t0 = pl.program_id(2) * pu if n_t > 1 else 0
+    ts = pl.program_id(2) if n_t > 1 else 0
     co = pl.program_id(3)
-    k0 = pl.program_id(4) * u if n_k > 1 else 0
+    ks = pl.program_id(4) if n_k > 1 else 0
     # Activation-gradient masking IN-VMEM on the resident cotangent block
     # (DESIGN.md Sec. 2.8): dym = dy * act'(y) is the masked (unscaled)
     # cotangent feeding the bias gradient; dx/dW additionally carry the
     # epilogue's scalar scale.  Padded positions stay zero (dy pad is 0).
-    dyv = dy_ref[0]
-    dym = dyv if y_ref is None else (
-        dyv * ep.grad_factor(y_ref[0]).astype(dyv.dtype))
-    dyv = dym if ep is None or ep.scale is None else dym * ep.scale
-    xv = x_ref[0]
+    # Every window is read from the resident dy (and y) block refs, so
+    # the mask is applied per window.
+    scale = None if ep is None else ep.scale
+
+    def cot(h0, w0, nh, nw):
+        """Masked, unscaled cotangent window of the padded dy block."""
+        win = read_window(dy_ref, (0,), h0, w0, oh=nh, ow=nw)
+        if y_ref is None:
+            return win
+        y_win = read_window(y_ref, (0,), h0, w0, oh=nh, ow=nw)
+        return win * ep.grad_factor(y_win).astype(win.dtype)
+
     # The shared residency: the filter-grad side's UNPADDED error window
-    # is a static slice of the same VMEM-resident padded dy block the
-    # input-grad windows come from -- dy is fetched exactly once.
-    rhs_fg = dyv[pad_h:pad_h + oh, pad_w:pad_w + ow].reshape(
-        oh * ow, dyv.shape[-1]).astype(jnp.float32)
+    # is a read of the same VMEM-resident padded dy block the input-grad
+    # windows come from -- dy is fetched exactly once.
+    co_w = dy_ref.shape[-1]
+    dym_fg = cot(pad_h, pad_w, oh, ow).reshape(oh * ow, co_w).astype(
+        jnp.float32)
+    rhs_fg = dym_fg if scale is None else dym_fg * scale
     if db_ref is not None:
         # Bias gradient: channel-sum of the masked cotangent, accumulated
         # in-kernel as the launch's third output.  One contribution per
         # (batch, cout-tile) -- taken at the first (ci, phase, tap) step.
-        dbc = dym[pad_h:pad_h + oh, pad_w:pad_w + ow].astype(
-            jnp.float32).sum(axis=(0, 1))                # (co_t,)
+        dbc = dym_fg.sum(axis=0)                          # (co_t,)
         db_cols = slice(None) if n_co == 1 else pl.ds(co * co_t, co_t)
         take = []
         if n_ci > 1:
@@ -173,12 +186,10 @@ def _bwd_kernel(dy_ref, w_ref, x_ref, *refs, tpw: int, kp: int,
 
     cols = slice(None) if n_co == 1 else pl.ds(co * co_t, co_t)
     for p in range(pu):
-        t = t0 + p
-        a, bb = t // tpw, t % tpw
+        a, bb = split_index(ts, pu, p, tpw)
         acc = None
         for j in range(u):
-            k = k0 + j
-            uf, vf = k // kq, k % kq
+            uf, vf = split_index(ks, u, j, kq)
             # The shared (phase, slot) -> filter-tap enumeration.
             # Flipped-slot mapping (see pack_phase_filters): slot uf of
             # phase a holds tap kx = a + (KP-1-uf)*period; padding slots
@@ -198,21 +209,23 @@ def _bwd_kernel(dy_ref, w_ref, x_ref, *refs, tpw: int, kp: int,
             # -- dx: this (phase, tap)'s window of the padded dy block --
             start_h = pad_h - (a * dil_h) // sh - (kp - 1 - uf) * step_h
             start_w = pad_w - (bb * dil_w) // sw - (kq - 1 - vf) * step_w
-            if isinstance(start_h, int) and isinstance(start_w, int):
-                win = dyv[start_h:start_h + ho, start_w:start_w + wo]
-            else:
-                win = jax.lax.dynamic_slice(
-                    dyv, (start_h, start_w, 0), (ho, wo, dyv.shape[-1]))
-            lhs = win.reshape(ho * wo, win.shape[-1]).astype(jnp.float32)
+            win = cot(start_h, start_w, ho, wo)
+            if scale is not None:
+                win = win * scale
+            lhs = win.reshape(ho * wo, co_w).astype(jnp.float32)
             rhs = w_ref[p, j].astype(jnp.float32)        # (co_t, ci_t)
             prod = jax.lax.dot(lhs, rhs,
                                preferred_element_type=jnp.float32)
             acc = prod if acc is None else acc + prod
             # -- dW: the same slot's filter tap, gathered from x --
-            tap = gather_tap(xv, kx, ky, sh=sh, sw=sw, dh=dil_h,
+            # (a padding slot on a traced grid reads the last real tap's
+            # window so the read stays in the block; its product is
+            # masked out below)
+            tap = gather_tap(x_ref, (0,), _clamp(kx, kh - 1),
+                             _clamp(ky, kwf - 1), sh=sh, sw=sw, dh=dil_h,
                              dw=dil_w, oh=oh, ow=ow)
             lhs_w = tap.reshape(oh * ow,
-                                xv.shape[-1]).astype(jnp.float32)
+                                x_ref.shape[-1]).astype(jnp.float32)
             pw = jax.lax.dot_general(
                 lhs_w, rhs_fg, (((0,), (0,)), ((), ())),
                 preferred_element_type=jnp.float32)      # (ci_t, co_t)
@@ -259,7 +272,7 @@ def conv_backward_pallas(x: jax.Array, dy: jax.Array, w: jax.Array, *,
                          cout_tile: int | None = None,
                          tap_unroll: int | None = None,
                          phase_unroll: int | None = None,
-                         interpret: bool = True):
+                         interpret: bool):
     """(dx, dW) of direct_conv(x, w, stride, padding, dilation) w.r.t.
     cotangent dy, in a SINGLE `pallas_call` with two output refs.
 
@@ -398,6 +411,7 @@ def conv_backward_pallas(x: jax.Array, dy: jax.Array, w: jax.Array, *,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=tiling.compiler_params(),
     )(*ins)
     dx_pm, dw_flat = outs[0], outs[1]
 
@@ -433,14 +447,22 @@ def _ct_bwd_kernel(g_ref, w_ref, dy_ref, *refs, sh: int,
     ddy_ref, dw_ref = refs[1 if has_z else 0], refs[2 if has_z else 1]
     db_ref = refs[-1] if has_db else None
     b, ci, co = pl.program_id(0), pl.program_id(1), pl.program_id(2)
-    t0 = pl.program_id(3) * u if n_t > 1 else 0
+    ts = pl.program_id(3) if n_t > 1 else 0
     # In-VMEM activation-gradient mask on the resident cotangent block:
-    # every tap gather below reads the masked g, so both matmuls (ddy
-    # and dW) see the epilogue's pullback without an extra HBM pass.
-    gv = g_ref[0]
-    gm = gv if z_ref is None else (
-        gv * ep.grad_factor(z_ref[0]).astype(gv.dtype))
-    gv = gm if ep is None or ep.scale is None else gm * ep.scale
+    # every tap gather below reads g (and z) from the resident blocks and
+    # masks the window, so both matmuls (ddy and dW) see the epilogue's
+    # pullback without an extra HBM pass.
+    scale = None if ep is None else ep.scale
+
+    def g_tap(kx, ky):
+        tap = gather_tap(g_ref, (0,), kx, ky, sh=sh, sw=sw, dh=dil_h,
+                         dw=dil_w, oh=oh, ow=ow)         # (oh, ow, ci_t)
+        if z_ref is not None:
+            z_tap = gather_tap(z_ref, (0,), kx, ky, sh=sh, sw=sw, dh=dil_h,
+                               dw=dil_w, oh=oh, ow=ow)
+            tap = tap * ep.grad_factor(z_tap).astype(tap.dtype)
+        return tap if scale is None else tap * scale
+
     rhs_fg = dy_ref[0].reshape(oh * ow, co_t).astype(jnp.float32)
     ci_cols = slice(None) if n_ci == 1 else pl.ds(ci * ci_t, ci_t)
     co_cols = slice(None) if n_co == 1 else pl.ds(co * co_t, co_t)
@@ -448,6 +470,9 @@ def _ct_bwd_kernel(g_ref, w_ref, dy_ref, *refs, sh: int,
         # Bias gradient over the tconv's OUTPUT channels (Cin): sum of
         # the masked (unscaled) cotangent, one contribution per
         # (batch, cin-tile) at the first (cout, tap) step.
+        gm = g_ref[0]
+        if z_ref is not None:
+            gm = gm * ep.grad_factor(z_ref[0]).astype(gm.dtype)
         dbc = gm.astype(jnp.float32).sum(axis=(0, 1))       # (ci_t,)
         take = []
         if n_co > 1:
@@ -471,11 +496,10 @@ def _ct_bwd_kernel(g_ref, w_ref, dy_ref, *refs, sh: int,
                 db_ref[0, ci_cols] += dbc
     acc_f = None
     for j in range(u):
-        t = t0 + j
-        kx, ky = t // kwf, t % kwf
+        t = ts * u + j
+        kx, ky = split_index(ts, u, j, kwf)
         # ONE tap gather of the resident g block feeds BOTH matmuls.
-        tap = gather_tap(gv, kx, ky, sh=sh, sw=sw, dh=dil_h, dw=dil_w,
-                         oh=oh, ow=ow)                   # (oh, ow, ci_t)
+        tap = g_tap(kx, ky)
         lhs = tap.reshape(oh * ow, ci_t).astype(jnp.float32)
         wt = w_ref[j].astype(jnp.float32)                # (ci_t, co_t)
         prod_f = jax.lax.dot(lhs, wt, preferred_element_type=jnp.float32)
@@ -521,7 +545,7 @@ def tconv_backward_pallas(g: jax.Array, dy: jax.Array, w: jax.Array, *,
                           cin_tile: int | None = None,
                           cout_tile: int | None = None,
                           tap_unroll: int | None = None,
-                          interpret: bool = True):
+                          interpret: bool):
     """(ddy, dW) of the transposed conv z = tconv(dy, w) w.r.t. cotangent
     g, in a SINGLE `pallas_call` with two output refs.
 
@@ -639,6 +663,7 @@ def tconv_backward_pallas(g: jax.Array, dy: jax.Array, w: jax.Array, *,
         out_specs=out_specs,
         out_shape=out_shape,
         interpret=interpret,
+        compiler_params=tiling.compiler_params(),
     )(*ins)
     ddy, dw_flat = outs[0], outs[1]
     if Cout % co_t:
@@ -665,7 +690,8 @@ def _backward_runner(spec: ConvSpec, x_shape, dy_shape, epilogue=None):
                   jnp.float32)
     y = (jnp.zeros(dy_shape, jnp.float32)
          if epilogue is not None and epilogue.needs_y else None)
-    interp = jax.default_backend() != "tpu"
+    from repro.kernels.ops import interpret_mode
+    interp = interpret_mode()
 
     def run(plan: tiling.TilePlan):
         return jax.block_until_ready(conv_backward_pallas(
@@ -687,7 +713,8 @@ def _ct_backward_runner(spec: ConvSpec, x_shape, dy_shape, epilogue=None):
                   jnp.float32)
     z = (jnp.zeros(x_shape, jnp.float32)
          if epilogue is not None and epilogue.needs_y else None)
-    interp = jax.default_backend() != "tpu"
+    from repro.kernels.ops import interpret_mode
+    interp = interpret_mode()
 
     def run(plan: tiling.TilePlan):
         return jax.block_until_ready(tconv_backward_pallas(

@@ -56,19 +56,19 @@ from jax.experimental import pallas as pl
 
 from repro.core.spec import ConvSpec, _pair
 from repro.kernels import tiling
-from repro.kernels.tap_gather import gather_tap, pad_to_tap_windows
+from repro.kernels.tap_gather import (gather_tap, pad_to_tap_windows,
+                                      split_index)
 
 
 def _fg_kernel(x_ref, dy_ref, out_ref, *, sh: int, sw: int, dh: int,
                dw: int, sp: int, ow: int, kw: int, u: int, n_t: int,
                seq1: bool):
-    # With a single tap step, t0 is a python int and every tap gather
-    # below lowers to STATIC strided slices of the resident block.
-    t0 = pl.program_id(4) * u if n_t > 1 else 0
+    # With a single tap step, ts is a python int and every tap gather
+    # below is a STATIC strided read of the resident block.
+    ts = pl.program_id(4) if n_t > 1 else 0
     ci_t = x_ref.shape[-1]
     co_t = dy_ref.shape[-1]
     rhs = dy_ref[0, 0].reshape(sp * ow, co_t).astype(jnp.float32)
-    xv = x_ref[0, 0]
     # seq1: B == n_sp == 1, so every visit to an out row is its first --
     # the init/accumulate predication compiles away entirely.
     first = None if seq1 else ((pl.program_id(2) == 0)
@@ -83,9 +83,9 @@ def _fg_kernel(x_ref, dy_ref, out_ref, *, sh: int, sw: int, dh: int,
             out_ref[pl.ds(t, 1)] = prod[None]
 
     for j in range(u):
-        t = t0 + j
-        kx, ky = t // kw, t % kw
-        tap = gather_tap(xv, kx, ky, sh=sh, sw=sw, dh=dh, dw=dw,
+        t = ts * u + j
+        kx, ky = split_index(ts, u, j, kw)
+        tap = gather_tap(x_ref, (0, 0), kx, ky, sh=sh, sw=sw, dh=dh, dw=dw,
                          oh=sp, ow=ow)                 # (sp, ow, ci_t)
         lhs = tap.reshape(sp * ow, ci_t).astype(jnp.float32)
         # One PE-column block per tap: (ci_t x sp*ow) @ (sp*ow x co_t).
@@ -117,7 +117,7 @@ def dconv_filter_grad_pallas(x: jax.Array, dy: jax.Array, *, stride,
                              cout_tile: int | None = None,
                              spatial_tile: int | None = None,
                              tap_unroll: int | None = None,
-                             interpret: bool = True) -> jax.Array:
+                             interpret: bool) -> jax.Array:
     """dW (Kh,Kw,Cin,Cout) for direct_conv(x, w, stride, padding, dilation).
 
     SINGLE `pallas_call`; the input is padded once and tap windows are
@@ -195,6 +195,7 @@ def dconv_filter_grad_pallas(x: jax.Array, dy: jax.Array, *, stride,
         out_shape=jax.ShapeDtypeStruct((T, n_ci * ci_t, n_co * co_t),
                                        jnp.float32),
         interpret=interpret,
+        compiler_params=tiling.compiler_params(),
     )(x_sl, dy_sl)
     if Cin % ci_t or Cout % co_t:   # slice only when padding occurred
         out = out[:, :Cin, :Cout]
@@ -206,7 +207,8 @@ def _autotune_runner(spec: ConvSpec, x_shape, dy_shape):
     proxy operands; geometry, not values, determines the timing)."""
     x = jnp.zeros(x_shape, jnp.float32)
     dy = jnp.zeros(dy_shape, jnp.float32)
-    interp = jax.default_backend() != "tpu"
+    from repro.kernels.ops import interpret_mode
+    interp = interpret_mode()
 
     def run(plan: tiling.TilePlan):
         return jax.block_until_ready(dconv_filter_grad_pallas(

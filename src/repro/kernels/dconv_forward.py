@@ -9,8 +9,9 @@ TPU mapping (the EcoFlow -> MXU translation, see DESIGN.md Sec. 2.4): the
 **dilation taps are the grid** -- ONE `pallas_call` with the useful-tap
 index t = kx*Kw + ky as its innermost (sequential) axis.  Each grid step
 realizes one per-tap multicast group inside the kernel: the once-padded
-input block is VMEM-resident, the step `dynamic_slice`s its tap window at
-offset (kx*D_h, ky*D_w), subsamples by the output stride, and contracts
+input block is VMEM-resident, the step reads its tap window at offset
+(kx*D_h, ky*D_w) from the block ref, strided by the output stride
+(`tap_gather.gather_tap`), and contracts
 the gathered (Oh*Ow, Cin_t) slab with that tap's (Cin_t, Cout_t) weights
 on the MXU.  Partial products accumulate into the fp32 output tile across
 the sequential (Cin-tile, tap) steps -- the Pallas equivalent of the
@@ -40,7 +41,8 @@ from jax.experimental import pallas as pl
 
 from repro.core.spec import ConvSpec, _pair
 from repro.kernels import tiling
-from repro.kernels.tap_gather import gather_tap, pad_to_tap_windows
+from repro.kernels.tap_gather import (gather_tap, pad_to_tap_windows,
+                                      split_index)
 
 
 def _df_kernel(x_ref, w_ref, *refs, sh: int, sw: int, dh: int, dw: int,
@@ -52,16 +54,14 @@ def _df_kernel(x_ref, w_ref, *refs, sh: int, sw: int, dh: int, dw: int,
     bias_ref = refs[0] if len(refs) == 2 else None
     out_ref = refs[-1]
     ci = pl.program_id(2)
-    # With a single tap step, t0 is a python int and every tap gather
-    # below lowers to STATIC strided slices of the resident block.
-    t0 = pl.program_id(3) * u if n_t > 1 else 0
+    # With a single tap step, ts is a python int and every tap gather
+    # below is a STATIC strided read of the resident block.
+    ts = pl.program_id(3) if n_t > 1 else 0
     ci_t = x_ref.shape[-1]
-    xv = x_ref[0]
     acc = None
     for j in range(u):
-        t = t0 + j
-        kx, ky = t // kw, t % kw
-        tap = gather_tap(xv, kx, ky, sh=sh, sw=sw, dh=dh, dw=dw,
+        kx, ky = split_index(ts, u, j, kw)
+        tap = gather_tap(x_ref, (0,), kx, ky, sh=sh, sw=sw, dh=dh, dw=dw,
                          oh=oh, ow=ow)                 # (oh, ow, ci_t)
         lhs = tap.reshape(oh * ow, ci_t).astype(jnp.float32)
         rhs = w_ref[j].astype(jnp.float32)             # (ci_t, co_t)
@@ -108,7 +108,7 @@ def dconv_forward_pallas(x: jax.Array, w: jax.Array, *, stride=(1, 1),
                          cin_tile: int | None = None,
                          cout_tile: int | None = None,
                          tap_unroll: int | None = None,
-                         interpret: bool = True) -> jax.Array:
+                         interpret: bool) -> jax.Array:
     """Zero-free dilated forward conv in a SINGLE `pallas_call`.
 
     x: (B, Nh, Nw, Cin) input.
@@ -190,6 +190,7 @@ def dconv_forward_pallas(x: jax.Array, w: jax.Array, *, stride=(1, 1),
         out_shape=jax.ShapeDtypeStruct((B, Oh, Ow, n_co * co_t),
                                        jnp.float32),
         interpret=interpret,
+        compiler_params=tiling.compiler_params(),
     )(*ins)
     if Cout % co_t:   # slice only when channel padding occurred
         out = out[..., :Cout]
@@ -203,7 +204,8 @@ def _autotune_runner(spec: ConvSpec, x_shape, dy_shape, epilogue=None):
                   jnp.float32)
     bias = (jnp.zeros((dy_shape[-1],), jnp.float32)
             if epilogue is not None and epilogue.bias else None)
-    interp = jax.default_backend() != "tpu"
+    from repro.kernels.ops import interpret_mode
+    interp = interpret_mode()
 
     def run(plan: tiling.TilePlan):
         return jax.block_until_ready(dconv_forward_pallas(

@@ -116,6 +116,8 @@ def _ig_kernel(dy_ref, w_ref, *refs, sh: int, sw: int, dh: int, dw: int,
         if isinstance(start_h, int) and isinstance(start_w, int):
             win = up[start_h:start_h + fh, start_w:start_w + fw]
         else:
+            # Interpret mode only: Mosaic has no value-level dynamic_slice,
+            # so the compiled planner unrolls every tap (static offsets).
             win = jax.lax.dynamic_slice(
                 up, (start_h, start_w, 0), (fh, fw, up.shape[-1]))
         lhs = win.reshape(fh * fw, win.shape[-1]).astype(jnp.float32)
@@ -151,7 +153,7 @@ def tconv_implicit_gemm_pallas(dy: jax.Array, w: jax.Array, *, stride,
                                cin_tile: int | None = None,
                                cout_tile: int | None = None,
                                tap_unroll: int | None = None,
-                               interpret: bool = True) -> jax.Array:
+                               interpret: bool) -> jax.Array:
     """Predicated implicit-GEMM transposed conv in a SINGLE `pallas_call`,
     any (S, D).
 
@@ -234,6 +236,7 @@ def tconv_implicit_gemm_pallas(dy: jax.Array, w: jax.Array, *, stride,
         out_shape=jax.ShapeDtypeStruct((B, Fh, Fw, n_ci * ci_t),
                                        jnp.float32),
         interpret=interpret,
+        compiler_params=tiling.compiler_params(),
     )(*ins)
 
     if Cin % ci_t:   # slice only when channel padding occurred
@@ -268,7 +271,8 @@ def _autotune_runner(spec: ConvSpec, x_shape, dy_shape, epilogue=None):
     bias = (jnp.zeros((x_shape[-1],), jnp.float32)
             if epilogue is not None and epilogue.bias else None)
     n_out = (x_shape[1], x_shape[2])
-    interp = jax.default_backend() != "tpu"
+    from repro.kernels.ops import interpret_mode
+    interp = interpret_mode()
 
     def run(plan: tiling.TilePlan):
         return jax.block_until_ready(tconv_implicit_gemm_pallas(

@@ -50,16 +50,20 @@ from repro.kernels.implicit_gemm import tconv_implicit_gemm_pallas
 from repro.kernels.tconv_phase import tconv_fused_pallas
 
 
-def _interpret() -> bool:
+def interpret_mode() -> bool:
     """True off-TPU (run the kernels in interpret mode), resolved lazily
-    at call time -- see the module docstring."""
+    at call time -- see the module docstring.  The ONE place the repo
+    decides between interpret and compiled Pallas: every wrapper here,
+    the autotune runners and the serving engine ask it, and the kernels
+    themselves take `interpret` as a required argument, so patching this
+    function steers a whole traced step."""
     return jax.default_backend() != "tpu"
 
 
 def flash_attention(q, k, v, *, causal=True, blk_q=128, blk_k=128):
     """Blockwise causal GQA attention via the Pallas flash kernel."""
     return flash_attention_pallas(q, k, v, causal=causal, blk_q=blk_q,
-                                  blk_k=blk_k, interpret=_interpret())
+                                  blk_k=blk_k, interpret=interpret_mode())
 
 
 def tconv_phase(dy: jax.Array, w: jax.Array, *, stride, padding,
@@ -86,14 +90,14 @@ def tconv_phase(dy: jax.Array, w: jax.Array, *, stride, padding,
     strategy, plan = tiling.plan_strategy(
         "input_grad", spec, x_shape=(dy.shape[0], nh, nw, w.shape[2]),
         dy_shape=dy.shape, itemsize=dy.dtype.itemsize,
-        interpret=_interpret(), epilogue=epilogue, strategy=strategy)
+        interpret=interpret_mode(), epilogue=epilogue, strategy=strategy)
     if strategy == "implicit_gemm":
         return tconv_implicit_gemm_pallas(
             dy, w, stride=tuple(stride), padding=tuple(padding),
             n_out=(nh, nw), dilation=tuple(dilation),
             bias=bias, epilogue=epilogue,
             cin_tile=plan.cin_tile, cout_tile=plan.cout_tile,
-            tap_unroll=plan.tap_unroll, interpret=_interpret())
+            tap_unroll=plan.tap_unroll, interpret=interpret_mode())
     return tconv_fused_pallas(dy, w, stride=tuple(stride),
                               padding=tuple(padding), n_out=(nh, nw),
                               dilation=tuple(dilation),
@@ -102,7 +106,7 @@ def tconv_phase(dy: jax.Array, w: jax.Array, *, stride, padding,
                               cout_tile=plan.cout_tile,
                               tap_unroll=plan.tap_unroll,
                               phase_unroll=plan.phase_unroll,
-                              interpret=_interpret())
+                              interpret=interpret_mode())
 
 
 def dconv_filter_grad(x: jax.Array, dy: jax.Array, *, stride, padding,
@@ -112,7 +116,7 @@ def dconv_filter_grad(x: jax.Array, dy: jax.Array, *, stride, padding,
                          dilation=dilation)
     plan = tiling.plan_tiles("filter_grad", spec, x_shape=x.shape,
                              dy_shape=dy.shape, itemsize=x.dtype.itemsize,
-                             interpret=_interpret())
+                             interpret=interpret_mode())
     return dconv_filter_grad_pallas(x, dy, stride=tuple(stride),
                                     padding=tuple(padding), k=tuple(k),
                                     dilation=tuple(dilation),
@@ -120,7 +124,7 @@ def dconv_filter_grad(x: jax.Array, dy: jax.Array, *, stride, padding,
                                     cout_tile=plan.cout_tile,
                                     spatial_tile=plan.spatial_tile,
                                     tap_unroll=plan.tap_unroll,
-                                    interpret=_interpret())
+                                    interpret=interpret_mode())
 
 
 def conv_backward(x: jax.Array, dy: jax.Array, w: jax.Array, *, stride,
@@ -141,7 +145,7 @@ def conv_backward(x: jax.Array, dy: jax.Array, w: jax.Array, *, stride,
     plan = tiling.plan_tiles("backward", spec, x_shape=x.shape,
                              dy_shape=dy.shape,
                              itemsize=dy.dtype.itemsize,
-                             interpret=_interpret(), epilogue=epilogue)
+                             interpret=interpret_mode(), epilogue=epilogue)
     return conv_backward_pallas(x, dy, w, stride=spec.stride,
                                 padding=spec.padding, n_out=(nh, nw),
                                 dilation=spec.dilation,
@@ -150,7 +154,7 @@ def conv_backward(x: jax.Array, dy: jax.Array, w: jax.Array, *, stride,
                                 cout_tile=plan.cout_tile,
                                 tap_unroll=plan.tap_unroll,
                                 phase_unroll=plan.phase_unroll,
-                                interpret=_interpret())
+                                interpret=interpret_mode())
 
 
 def tconv_backward(g: jax.Array, dy: jax.Array, w: jax.Array, *, stride,
@@ -170,7 +174,7 @@ def tconv_backward(g: jax.Array, dy: jax.Array, w: jax.Array, *, stride,
     plan = tiling.plan_tiles("ct_backward", spec, x_shape=g.shape,
                              dy_shape=dy.shape,
                              itemsize=g.dtype.itemsize,
-                             interpret=_interpret(), epilogue=epilogue)
+                             interpret=interpret_mode(), epilogue=epilogue)
     return tconv_backward_pallas(g, dy, w, stride=spec.stride,
                                  padding=spec.padding,
                                  dilation=spec.dilation,
@@ -178,7 +182,7 @@ def tconv_backward(g: jax.Array, dy: jax.Array, w: jax.Array, *, stride,
                                  cin_tile=plan.cin_tile,
                                  cout_tile=plan.cout_tile,
                                  tap_unroll=plan.tap_unroll,
-                                 interpret=_interpret())
+                                 interpret=interpret_mode())
 
 
 def dconv_forward(x: jax.Array, w: jax.Array, *, stride, padding,
@@ -200,11 +204,11 @@ def dconv_forward(x: jax.Array, w: jax.Array, *, stride, padding,
         return dconv_forward_pallas(x, w, stride=tuple(stride),
                                     padding=tuple(padding),
                                     dilation=tuple(dilation),
-                                    interpret=_interpret())
+                                    interpret=interpret_mode())
     plan = tiling.plan_tiles("forward", spec, x_shape=x.shape,
                              dy_shape=(x.shape[0], oh, ow, w.shape[3]),
                              itemsize=x.dtype.itemsize,
-                             interpret=_interpret(), epilogue=epilogue)
+                             interpret=interpret_mode(), epilogue=epilogue)
     return dconv_forward_pallas(x, w, stride=tuple(stride),
                                 padding=tuple(padding),
                                 dilation=tuple(dilation),
@@ -212,4 +216,4 @@ def dconv_forward(x: jax.Array, w: jax.Array, *, stride, padding,
                                 cin_tile=plan.cin_tile,
                                 cout_tile=plan.cout_tile,
                                 tap_unroll=plan.tap_unroll,
-                                interpret=_interpret())
+                                interpret=interpret_mode())

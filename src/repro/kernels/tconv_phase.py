@@ -22,7 +22,7 @@ upsampling or filter dilation) is ever stored, moved, or multiplied.
 TPU mapping (the EcoFlow -> MXU translation, see DESIGN.md Sec. 2/2.5):
   * the paper's phase enumeration (symbolic outer product grouped by
     output residue) becomes the phase grid axis;
-  * the per-tap multicast group becomes a `dynamic_slice` window of the
+  * the per-tap multicast group becomes a window read (`pl.ds`) of the
     VMEM-resident padded dy block at the tap's (base + u*step) offset;
   * the vertical psum chain becomes the fp32 accumulator tile, summed
     sequentially over the (Cout-tile, tap) grid axes;
@@ -55,6 +55,7 @@ from jax.experimental import pallas as pl
 from repro.core import ecoflow
 from repro.core.spec import ConvSpec, _pair
 from repro.kernels import tiling
+from repro.kernels.tap_gather import read_window, split_index
 
 
 def pack_phase_filters(w: jax.Array, stride, dilation=(1, 1)) -> jax.Array:
@@ -172,12 +173,12 @@ def _fused_tap_kernel(dy_ref, w_ref, *refs, tpw: int, kp: int, kq: int,
                       step_w: int, pad_h: int, pad_w: int, ho: int, wo: int,
                       pu: int, n_t: int, u: int, n_k: int, seq1: bool,
                       ep=None):
-    """`pu` phases x `u` taps per sequential grid step: `dynamic_slice`
-    each tap's window out of the VMEM-resident padded dy block, one MXU
+    """`pu` phases x `u` taps per sequential grid step: read each tap's
+    window out of the VMEM-resident padded dy block, one MXU
     matmul per tap with its (Cout_t, Cin_t) weights, accumulate each
     phase's fp32 tile across the (Cout-tile, tap-step) axes.
     When a single (phase, tap) grid step remains, every window offset is
-    a python int and the gathers lower to STATIC slices -- and the
+    a python int and the reads are STATIC -- and the
     zero-padded slots of ragged phases (slot tap index kx >= K) are
     SKIPPED outright via the shared (phase, slot) -> filter-tap validity
     test, the same static skip the fused backward kernel applies
@@ -189,10 +190,9 @@ def _fused_tap_kernel(dy_ref, w_ref, *refs, tpw: int, kp: int, kq: int,
     each finished phase plane before its HBM store."""
     bias_ref = refs[0] if len(refs) == 2 else None
     out_ref = refs[-1]
-    t0 = pl.program_id(1) * pu if n_t > 1 else 0
+    ts = pl.program_id(1) if n_t > 1 else 0
     co = pl.program_id(3)
-    k0 = pl.program_id(4) * u if n_k > 1 else 0
-    dyv = dy_ref[0]
+    ks = pl.program_id(4) if n_k > 1 else 0
     traced = n_t > 1 or n_k > 1
     # seq1: single sequential (Cout-tile, tap) step -> every visit to an
     # out block is its first, the predication compiles away.
@@ -208,12 +208,10 @@ def _fused_tap_kernel(dy_ref, w_ref, *refs, tpw: int, kp: int, kq: int,
         return ep.apply(vals, None if bias_ref is None else bias_ref[0])
 
     for p in range(pu):
-        t = t0 + p
-        a, b = t // tpw, t % tpw
+        a, b = split_index(ts, pu, p, tpw)
         acc = None
         for j in range(u):
-            k = k0 + j
-            uf, vf = k // kq, k % kq
+            uf, vf = split_index(ks, u, j, kq)
             if not traced:
                 # Static slot: skip padding slots of ragged phases -- the
                 # slot's filter tap falls outside the K x K extent, its
@@ -227,11 +225,7 @@ def _fused_tap_kernel(dy_ref, w_ref, *refs, tpw: int, kp: int, kq: int,
             # shifted into the padded frame.
             start_h = pad_h - (a * dh) // sh - (kp - 1 - uf) * step_h
             start_w = pad_w - (b * dw) // sw - (kq - 1 - vf) * step_w
-            if isinstance(start_h, int) and isinstance(start_w, int):
-                win = dyv[start_h:start_h + ho, start_w:start_w + wo]
-            else:
-                win = jax.lax.dynamic_slice(
-                    dyv, (start_h, start_w, 0), (ho, wo, dyv.shape[-1]))
+            win = read_window(dy_ref, (0,), start_h, start_w, oh=ho, ow=wo)
             lhs = win.reshape(ho * wo, win.shape[-1]).astype(jnp.float32)
             rhs = w_ref[p, j].astype(jnp.float32)    # (co_t, ci_t)
             prod = jax.lax.dot(lhs, rhs,
@@ -268,7 +262,7 @@ def tconv_fused_pallas(dy: jax.Array, w: jax.Array, *, stride, padding=(0, 0),
                        cout_tile: int | None = None,
                        tap_unroll: int | None = None,
                        phase_unroll: int | None = None,
-                       interpret: bool = True) -> jax.Array:
+                       interpret: bool) -> jax.Array:
     """Zero-free transposed conv in a SINGLE `pallas_call`, any (S, D).
 
     dy: (B, Oh, Ow, Cout) error / generator input.
@@ -370,6 +364,7 @@ def tconv_fused_pallas(dy: jax.Array, w: jax.Array, *, stride, padding=(0, 0),
         out_shape=jax.ShapeDtypeStruct((B, T, ho, wo, n_ci * ci_t),
                                        jnp.float32),
         interpret=interpret,
+        compiler_params=tiling.compiler_params(),
     )(*ins)
 
     if Cin % ci_t:   # slice only when channel padding occurred
@@ -393,7 +388,8 @@ def _autotune_runner(spec: ConvSpec, x_shape, dy_shape, epilogue=None):
     bias = (jnp.zeros((x_shape[-1],), jnp.float32)
             if epilogue is not None and epilogue.bias else None)
     n_out = (x_shape[1], x_shape[2])
-    interp = jax.default_backend() != "tpu"
+    from repro.kernels.ops import interpret_mode
+    interp = interpret_mode()
 
     def run(plan: tiling.TilePlan):
         return jax.block_until_ready(tconv_fused_pallas(

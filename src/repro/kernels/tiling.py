@@ -25,8 +25,8 @@ Two modes:
     with `benchmarks.wallclock._time` (median-of-iters) when the
     benchmarks package is importable, else a local fallback with the same
     semantics.  Winners persist to a JSON cache keyed by (op, geometry)
-    (`ECOFLOW_TILE_CACHE`, default ~/.cache/ecoflow/tile_cache.json) so a
-    sweep is paid once per geometry per host.
+    (`ECOFLOW_TILE_CACHE`, default `.ecoflow_cache/tile_cache.json` in
+    the checkout) so a sweep is paid once per geometry per host.
 
 Beyond tiles, the planner picks the *strategy*: `plan_strategy` races the
 phase decomposition against the predicated implicit-GEMM formulation
@@ -55,7 +55,10 @@ guessing at them:
     because Mosaic kernel code size, not VMEM, is the binding constraint;
   * channel tiles prefer the exact channel count when it is small enough
     to fit (no host-side pad/slice at all) and MXU-aligned powers of two
-    otherwise.
+    otherwise -- in compiled mode only the full extent or a multiple of
+    128 lanes, the only channel blocks Mosaic accepts;
+  * a compiled launch's working set counts each channel axis at its
+    128-lane VMEM layout width (a 3-channel block occupies 128 lanes).
 
 See DESIGN.md Sec. 2.6 for the policy rules and the cache format.
 """
@@ -73,12 +76,25 @@ from typing import Callable, Dict, Optional
 
 from repro.core import ecoflow
 from repro.core.spec import ConvSpec, Epilogue
+from repro.runtime import REPO_ROOT
 
 # Fraction of a TPU core's ~16 MiB VMEM the planner budgets for one
 # kernel's resident blocks (the rest covers double-buffering slack,
 # scalar state, and the compiler's own scratch).  Overridable per call
 # and via ECOFLOW_VMEM_BUDGET (bytes).
 DEFAULT_VMEM_BUDGET = 8 * 2 ** 20
+
+# Scoped-VMEM limit every compiled conv kernel is built with.  The
+# compiler's default (16 MiB) cannot hold the full-frame blocks of a
+# 128x128 image once a narrow channel axis is padded to 128 lanes, plus
+# Mosaic's own spill space for the per-tap matmul operands; a v5e core
+# has 128 MiB of VMEM.  The planner still ranks candidates against
+# DEFAULT_VMEM_BUDGET -- this is headroom, not a bigger plan.
+VMEM_LIMIT_BYTES = 64 * 2 ** 20
+
+# VMEM lane width: the minor (channel) dim of every block is laid out in
+# multiples of it on the chip.
+LANES = 128
 
 # Modeled cost of one grid step, in traffic-equivalent bytes.  The
 # interpret emulation re-materializes every block and re-dispatches the
@@ -154,17 +170,31 @@ class TilePlan:
 # Candidate enumeration
 # ---------------------------------------------------------------------------
 
-def _channel_candidates(c: int) -> tuple[int, ...]:
+def _channel_candidates(c: int, interpret: bool) -> tuple[int, ...]:
     """Candidate channel-tile extents for a `c`-channel axis: the exact
     count when small enough to be a single unpadded tile, MXU-aligned
-    powers of two below it otherwise."""
+    powers of two below it otherwise.  Compiled (Mosaic) blocks need the
+    channel extent -- a block's minor dim -- to be the full axis or a
+    multiple of 128 lanes, so the narrower splits are interpret-only."""
     cands = {min(c, 256)}
     if c <= 256:
         cands.add(c)  # exact: no pad, no slice
     for p in (256, 128, 64, 32, 16, 8):
-        if p < c:
+        if p < c and (interpret or p % LANES == 0):
             cands.add(p)
     return tuple(sorted(cands, reverse=True))
+
+
+def compiler_params():
+    """Mosaic parameters every conv kernel is built with (ignored in
+    interpret mode)."""
+    from jax.experimental.pallas import tpu as pltpu
+    return pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
+
+
+def _lanes(c: int) -> int:
+    """Channel extent rounded up to the VMEM lane width."""
+    return _cdiv(c, LANES) * LANES
 
 
 def _spatial_candidates(oh: int) -> tuple[int, ...]:
@@ -473,18 +503,21 @@ def strategy_supported(op: str, strategy: str) -> bool:
     return _model_key(op, strategy) in _MODELS
 
 
-def _candidates(op: str, g: _Geom, strategy: str = "phase"):
+def _candidates(op: str, g: _Geom, strategy: str, interpret: bool):
     """The candidate (ci_t, co_t, sp_t, u, pu) lattice for one
     (op, strategy) family.  `u` ranges over divisors of the family's
     tap-axis extent: Kh*Kw for the tap-on-grid kernels (including the
     implicit-GEMM flat-tap grid), KP*KQ packed taps per phase for the
     unified phase input gradient -- whose phase axis additionally unrolls
     by `pu` (a divisor of the non-empty phase count).  Only the
-    filter-grad grid spatially tiles."""
+    filter-grad grid spatially tiles.  Compiled unrolls cover whole tap
+    (and phase) rows, and compiled implicit-GEMM unrolls every tap: its
+    windows are slices of an in-register frame, which Mosaic can only
+    take at static offsets."""
     kh, kw = g.spec.filter_shape
     t = kh * kw
-    ci_cands = _channel_candidates(g.cin)
-    co_cands = _channel_candidates(g.cout)
+    ci_cands = _channel_candidates(g.cin, interpret)
+    co_cands = _channel_candidates(g.cout, interpret)
     sp_cands = _spatial_candidates(g.oh) if op == "filter_grad" \
         else (g.oh,)
     if op in ("input_grad", "backward") and strategy == "phase":
@@ -492,9 +525,22 @@ def _candidates(op: str, g: _Geom, strategy: str = "phase"):
         tph, tpw = g.spec.n_tap_phases
         u_cands = _divisors(kp * kq)
         pu_cands = _divisors(tph * tpw)
+        w_minor = (kq, tpw)
+    elif strategy == "implicit_gemm" and not interpret:
+        u_cands = (t,)
+        pu_cands = (1,)
+        w_minor = (1, 1)
     else:
         u_cands = _divisors(t)
         pu_cands = (1,)
+        w_minor = (kw, 1)
+    if not interpret:
+        # Mosaic reads a window at a traced W (sublane) offset only when
+        # it can prove alignment, so compiled steps unroll whole rows of
+        # taps / phases: the W offset of every window stays static
+        # (`tap_gather.split_index`) and only the H offset is traced.
+        u_cands = tuple(v for v in u_cands if v % w_minor[0] == 0)
+        pu_cands = tuple(v for v in pu_cands if v % w_minor[1] == 0)
     for ci_t in ci_cands:
         for co_t in co_cands:
             for sp_t in sp_cands:
@@ -503,11 +549,24 @@ def _candidates(op: str, g: _Geom, strategy: str = "phase"):
                         yield ci_t, co_t, sp_t, u, pu
 
 
+def _working_set(op: str, g: _Geom, ci_t, co_t, sp_t, u, pu, interpret,
+                 ep=None, strategy: str = "phase") -> int:
+    """Modeled VMEM bytes of one candidate; compiled blocks count each
+    channel axis at its lane-padded layout width."""
+    if not interpret:
+        g = dataclasses.replace(g, cin=_lanes(g.cin), cout=_lanes(g.cout))
+        ci_t, co_t = _lanes(ci_t), _lanes(co_t)
+    return _MODELS[_model_key(op, strategy)](g, ci_t, co_t, sp_t, u, pu,
+                                             ep=ep)[0]
+
+
 def _score(op: str, g: _Geom, ci_t, co_t, sp_t, u, pu, budget, interpret,
            ep=None, strategy: str = "phase"):
     """Modeled cost of one candidate, or None if it violates a constraint."""
-    ws, traffic, steps, step_blk = _MODELS[_model_key(op, strategy)](
+    _, traffic, steps, step_blk = _MODELS[_model_key(op, strategy)](
         g, ci_t, co_t, sp_t, u, pu, ep=ep)
+    ws = _working_set(op, g, ci_t, co_t, sp_t, u, pu, interpret, ep,
+                      strategy)
     if ws > budget:
         return None
     if not interpret and pu * u > MAX_TAP_UNROLL_COMPILED:
@@ -528,8 +587,13 @@ def _analytical_best(op: str, spec: ConvSpec, x_shape, dy_shape,
     cost None when nothing fit and the minimum-footprint fallback was
     taken (the strategy race treats that as a loss)."""
     g = _geom(op, spec, x_shape, dy_shape, itemsize)
-    best, best_cost = None, None
-    for ci_t, co_t, sp_t, u, pu in _candidates(op, g, strategy):
+    best, best_cost, smallest = None, None, None
+    for cand in _candidates(op, g, strategy, interpret):
+        ci_t, co_t, sp_t, u, pu = cand
+        if interpret or pu * u <= MAX_TAP_UNROLL_COMPILED:
+            ws = _working_set(op, g, *cand, interpret, ep, strategy)
+            if smallest is None or ws < smallest[0]:
+                smallest = (ws, cand)
         cost = _score(op, g, ci_t, co_t, sp_t, u, pu, budget, interpret,
                       ep=ep, strategy=strategy)
         if cost is None:
@@ -539,8 +603,13 @@ def _analytical_best(op: str, spec: ConvSpec, x_shape, dy_shape,
         key = (cost, -ci_t * co_t, -u * pu, -sp_t)
         if best is None or key < best_cost:
             best, best_cost = (ci_t, co_t, sp_t, u, pu), key
-    if best is None:   # nothing fits: fall back to the smallest candidate
-        best = (min(8, g.cin), min(8, g.cout), 1, 1, 1)
+    if best is None:
+        # Nothing fits the budget: take the candidate with the smallest
+        # working set (legal by construction; the kernels' VMEM limit
+        # leaves headroom above the budget).  Compiled implicit-GEMM past
+        # the unroll cap has no candidate at all and never runs.
+        best = smallest[1] if smallest is not None else \
+            (min(8, g.cin), min(8, g.cout), 1, 1, 1)
     ci_t, co_t, sp_t, u, pu = best
     plan = TilePlan(cin_tile=ci_t, cout_tile=co_t, spatial_tile=sp_t,
                     tap_unroll=u, phase_unroll=pu,
@@ -643,11 +712,13 @@ def _median_time_us(fn, iters: int = 5, warmup: int = 2) -> float:
 
 
 def cache_path() -> pathlib.Path:
+    """`ECOFLOW_TILE_CACHE`, else a fixed file inside the checkout
+    (gitignored): a run plans from what the checkout holds, never from a
+    stray file in the user's home directory."""
     env = os.environ.get("ECOFLOW_TILE_CACHE")
     if env:
         return pathlib.Path(env)
-    return pathlib.Path(os.path.expanduser("~")) / ".cache" / "ecoflow" / \
-        "tile_cache.json"
+    return REPO_ROOT / ".ecoflow_cache" / "tile_cache.json"
 
 
 def _cache_key(op: str, spec: ConvSpec, x_shape, dy_shape, itemsize,
@@ -802,7 +873,7 @@ def _sweep(op: str, spec: ConvSpec, x_shape, dy_shape, itemsize, budget,
     g = _geom(op, spec, x_shape, dy_shape, itemsize)
     run = _call_runner_factory(factory, spec, x_shape, dy_shape, ep)
     best_plan, best_us = None, math.inf
-    for ci_t, co_t, sp_t, u, pu in _candidates(op, g, strategy):
+    for ci_t, co_t, sp_t, u, pu in _candidates(op, g, strategy, interpret):
         if _score(op, g, ci_t, co_t, sp_t, u, pu, budget,
                   interpret, ep=ep, strategy=strategy) is None:
             continue
@@ -1047,6 +1118,13 @@ def plan_strategy(op: str, spec: ConvSpec, *, x_shape, dy_shape,
                          f"{STRATEGIES + ('auto',)}")
     if strategy != "phase" and not strategy_supported(op, "implicit_gemm"):
         strategy = "phase"   # per-op fallback: no implicit-GEMM kernel
+    kh, kw = spec.filter_shape
+    if strategy != "phase" and not interpret \
+            and kh * kw > MAX_TAP_UNROLL_COMPILED:
+        # Compiled implicit-GEMM needs every tap unrolled (static window
+        # offsets into its in-register frame); past the cap it has no
+        # kernel Mosaic accepts.
+        strategy = "phase"
     if vmem_budget is None:
         vmem_budget = int(os.environ.get("ECOFLOW_VMEM_BUDGET",
                                          DEFAULT_VMEM_BUDGET))

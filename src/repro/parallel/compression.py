@@ -46,7 +46,6 @@ def compressed_psum(x: jax.Array, axis_name: str, error: jax.Array
 def make_compressed_grad_allreduce(mesh, axis_name: str = "pod"):
     """Tree-level wrapper: returns f(grads, errors) -> (grads, errors)
     running one compressed all-reduce per leaf over `axis_name`."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     def per_leaf(g, e):
@@ -54,7 +53,7 @@ def make_compressed_grad_allreduce(mesh, axis_name: str = "pod"):
 
     def f(grads, errors):
         outs = jax.tree.map(
-            lambda g, e: shard_map(
+            lambda g, e: jax.shard_map(
                 functools.partial(per_leaf),
                 mesh=mesh,
                 in_specs=(P(*([None] * g.ndim)), P(*([None] * g.ndim))),

@@ -22,7 +22,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -66,13 +65,9 @@ def gpipe(mesh: Mesh, axis: str, stage_fn: Callable, stage_params, x,
 
         ys0 = jnp.zeros_like(xs)
         # carries become stage-varying after the first ppermute; mark the
-        # initial values as varying over the stage axis.  `lax.pcast` only
-        # exists once shard_map has varying-manual-axes tracking (jax>=0.8);
-        # on older jax the scan carry needs no annotation.
-        pcast = getattr(lax, "pcast", None)
-        if pcast is not None:
-            buf = pcast(buf, (axis,), to="varying")
-            ys0 = pcast(ys0, (axis,), to="varying")
+        # initial values as varying over the stage axis.
+        buf = lax.pcast(buf, (axis,), to="varying")
+        ys0 = lax.pcast(ys0, (axis,), to="varying")
         (_, ys), _ = lax.scan(tick, (buf, ys0), jnp.arange(n_ticks))
         # Broadcast the last stage's outputs to everyone.
         ys = lax.psum(jnp.where(stage == n_stages - 1, ys, 0.0), axis)
@@ -80,7 +75,7 @@ def gpipe(mesh: Mesh, axis: str, stage_fn: Callable, stage_params, x,
 
     pspec_params = jax.tree.map(lambda a: P(axis, *([None] * (a.ndim - 1))),
                                 stage_params)
-    f = shard_map(per_stage, mesh=mesh,
-                  in_specs=(pspec_params, P(*([None] * x.ndim))),
-                  out_specs=P(*([None] * x.ndim)))
+    f = jax.shard_map(per_stage, mesh=mesh,
+                      in_specs=(pspec_params, P(*([None] * x.ndim))),
+                      out_specs=P(*([None] * x.ndim)))
     return f(stage_params, x)

@@ -253,7 +253,8 @@ class ConvServeEngine:
         the primary rung with one dummy batch.  `shapes` lists
         ``(kind, payload_shape)`` pairs."""
         from repro.kernels import tiling
-        interpret = self._interpret()
+        from repro.kernels.ops import interpret_mode
+        interpret = interpret_mode()
         entries = []
         for kind, payload_shape in shapes:
             bucket = self._bucket(kind, tuple(payload_shape))
@@ -277,11 +278,6 @@ class ConvServeEngine:
                 np.asarray(self._jitted(bucket, self.ladder[0])(batch))
         self.stats["warmup"] = summary
         return summary
-
-    @staticmethod
-    def _interpret() -> bool:
-        import jax
-        return jax.default_backend() != "tpu"
 
     # -- admission --------------------------------------------------------
 

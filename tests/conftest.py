@@ -84,7 +84,7 @@ def pallas_block_shapes(fn, *args):
     every in/out BlockSpec (the kernel's VMEM working set)."""
     import jax
     jaxpr = jax.make_jaxpr(fn)(*args)
-    return [[tuple(bm.block_shape)
+    return [[tuple(getattr(d, "block_size", d) for d in bm.block_shape)
              for bm in e.params["grid_mapping"].block_mappings]
             for e in walk_eqns(jaxpr.jaxpr)
             if e.primitive.name == "pallas_call"]

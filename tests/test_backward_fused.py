@@ -237,7 +237,7 @@ def test_backward_no_duplicated_dy_intermediates(rng):
     jaxpr = jax.make_jaxpr(fn)(x, dy, w)
     dy_sized = []
     for e in walk_eqns(jaxpr.jaxpr):
-        if e.primitive.name in ("pjit", "custom_jvp_call",
+        if e.primitive.name in ("jit", "custom_jvp_call",
                                 "custom_vjp_call_jaxpr"):
             continue   # call wrappers re-report their sub-jaxpr's output
         for v in e.outvars:

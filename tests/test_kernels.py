@@ -469,7 +469,8 @@ def test_tconv_fully_unrolled_skips_padding_slots(rng):
     N = S * (O - 1) + K
     fn = lambda dy_, w_: tconv_fused_pallas(
         dy_, w_, stride=(S, S), padding=(0, 0), n_out=(N, N),
-        tap_unroll=4, phase_unroll=4, cin_tile=Ci, cout_tile=Co)
+        tap_unroll=4, phase_unroll=4, cin_tile=Ci, cout_tile=Co,
+        interpret=True)
     jaxpr = jax.make_jaxpr(fn)(dy, w)
     dots = [e for e in walk_eqns(jaxpr.jaxpr)
             if e.primitive.name == "dot_general"]

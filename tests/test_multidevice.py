@@ -301,16 +301,15 @@ def test_compressed_allreduce_across_pods():
     _run("""
     import jax, numpy as np, jax.numpy as jnp
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     from repro.parallel.compression import compressed_psum
 
     mesh = Mesh(np.asarray(jax.devices()).reshape(2, 4), ("pod", "data"))
     rng = np.random.default_rng(0)
     g = jnp.asarray(rng.normal(size=(2, 64)), jnp.float32)  # per-pod grads
     e = jnp.zeros_like(g)
-    f = shard_map(lambda gg, ee: compressed_psum(gg, "pod", ee),
-                  mesh=mesh, in_specs=(P("pod"), P("pod")),
-                  out_specs=(P("pod"), P("pod")))
+    f = jax.shard_map(lambda gg, ee: compressed_psum(gg, "pod", ee),
+                      mesh=mesh, in_specs=(P("pod"), P("pod")),
+                      out_specs=(P("pod"), P("pod")))
     out, err = f(g, e)
     want = g.mean(axis=0)
     # each pod's shard now holds (approximately) the mean

@@ -285,12 +285,12 @@ def test_error_feedback_unbiased_over_steps(rng):
     """With error feedback, the accumulated quantization error stays
     bounded (it does not grow with steps) -- the 1-bit-Adam property."""
     from jax.sharding import Mesh, PartitionSpec as P
-    from jax.experimental.shard_map import shard_map
     mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1), ("pod",))
     g = jnp.asarray(rng.normal(size=(32,)), jnp.float32)
     err = jnp.zeros_like(g)
-    f = shard_map(lambda gg, ee: compression.compressed_psum(gg, "pod", ee),
-                  mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
+    f = jax.shard_map(
+        lambda gg, ee: compression.compressed_psum(gg, "pod", ee),
+        mesh=mesh, in_specs=(P(), P()), out_specs=(P(), P()))
     total_true, total_sent = jnp.zeros_like(g), jnp.zeros_like(g)
     for _ in range(50):
         out, err = f(g, err)

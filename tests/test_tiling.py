@@ -19,7 +19,12 @@ def _shapes(B, N, O, Ci, Co):
 
 def test_plan_respects_vmem_budget():
     """Every returned plan's modeled working set fits the budget, across
-    op families and budgets."""
+    op families, budgets and both execution modes -- or, where no
+    candidate fits, the plan is the minimum-footprint candidate.
+    Compiled plans are scored at the lane-padded VMEM layout and may
+    only split channels into 128-lane tiles, so at this 256-channel
+    geometry the full-frame kernels have no fitting compiled candidate
+    below the default budget."""
     spec = ConvSpec.make(stride=2, padding=0, filter_shape=3)
     x_shape, dy_shape = _shapes(2, 127, 63, 256, 256)
     # filter_grad can always shrink its spatial slab to fit a tight
@@ -33,20 +38,33 @@ def test_plan_respects_vmem_budget():
         "filter_grad": (1 << 20, 4 << 20, tiling.DEFAULT_VMEM_BUDGET),
         "ct_backward": (tiling.DEFAULT_VMEM_BUDGET,),
     }
-    for op in tiling.OPS:
-        budgets = budgets_by_op.get(op,
-                                    (4 << 20, tiling.DEFAULT_VMEM_BUDGET))
-        for budget in budgets:
-            plan = tiling.plan_tiles(op, spec, x_shape=x_shape,
-                                     dy_shape=dy_shape,
-                                     vmem_budget=budget, interpret=False)
-            g = tiling._geom(op, spec, x_shape, dy_shape, 4)
-            ws, _, _, _ = tiling._MODELS[op](
-                g, plan.cin_tile, plan.cout_tile, plan.spatial_tile,
-                plan.tap_unroll, plan.phase_unroll)
-            assert ws <= budget, (op, budget, plan)
-            assert plan.grid_order == tiling._GRID_ORDERS[op]
-            assert plan.source == "analytical"
+    for interpret in (True, False):
+        for op in tiling.OPS:
+            budgets = budgets_by_op.get(op, (4 << 20,
+                                             tiling.DEFAULT_VMEM_BUDGET))
+            for budget in budgets:
+                plan = tiling.plan_tiles(op, spec, x_shape=x_shape,
+                                         dy_shape=dy_shape,
+                                         vmem_budget=budget,
+                                         interpret=interpret)
+                g = tiling._geom(op, spec, x_shape, dy_shape, 4)
+                ws = tiling._working_set(
+                    op, g, plan.cin_tile, plan.cout_tile,
+                    plan.spatial_tile, plan.tap_unroll, plan.phase_unroll,
+                    interpret)
+                cands = list(tiling._candidates(op, g, "phase", interpret))
+                if any(tiling._score(op, g, *c, budget, interpret)
+                       is not None for c in cands):
+                    assert ws <= budget, (op, budget, interpret, plan)
+                else:
+                    assert not interpret, (op, budget, plan)
+                    assert ws == min(
+                        tiling._working_set(op, g, *c, interpret)
+                        for c in cands
+                        if c[3] * c[4] <= tiling.MAX_TAP_UNROLL_COMPILED), (
+                        op, budget, plan)
+                assert plan.grid_order == tiling._GRID_ORDERS[op]
+                assert plan.source == "analytical"
 
 
 def test_exact_channel_tiles_preferred_when_small():
