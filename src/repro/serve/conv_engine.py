@@ -43,10 +43,25 @@ poison the host-materialized result.  With no injector attached the
 fast path is a plain jitted `generator_apply` / `atrous_head_apply`
 with `backend="pallas"` -- exactly ONE forward `pallas_call` per conv
 layer, same as the training stack (the jaxpr pins hold unmodified).
+
+Tracing.  The serving loop writes `jax.profiler.TraceAnnotation` spans,
+one set per cohort (never per request), into the profiler's own trace,
+on the device trace's clock; with no profiler running they record
+nothing.  `engine.cohort` is one iteration of `run()`'s loop and
+holds `engine.take` (`_take_cohort`), `engine.launch` (the whole ladder;
+args `kind`, `n`, `uid0` = the uid of the cohort's first request) and
+`engine.answer` (deadline check, latency record, results).
+`engine.launch` holds `engine.batch` (the slot batch and its payload
+copies) and, per attempt, `engine.dispatch` (injector hook and the
+jitted call; args `rung`, `attempt`), `engine.fetch` (`np.asarray` of
+the output) and `engine.check` (the host `isfinite`).  Building the
+first engine also registers a `python.gc` span around every collection
+of Python's cyclic garbage collector (arg `generation`).
 """
 from __future__ import annotations
 
 import dataclasses
+import gc
 import time
 from collections import deque
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -58,6 +73,9 @@ from repro.serve.faults import FaultInjector
 DEFAULT_LADDER = ("pallas", "xla_zero_free", "reference")
 
 KINDS = ("gan_gen", "aspp")
+
+# `health()`'s latency percentiles cover this many most recent answers.
+LATENCY_WINDOW = 10_000
 
 
 @dataclasses.dataclass
@@ -126,6 +144,31 @@ class CircuitBreaker:
             self._to("open")
 
 
+class _GcSpan:
+    """`gc.callbacks` entry that opens a `python.gc` span when a
+    collection starts and closes it when the collection stops, so a
+    trace labels the host pauses that are collections."""
+
+    def __init__(self, annotation):
+        self._annotation = annotation
+        self._open = None
+
+    def __call__(self, phase: str, info: dict) -> None:
+        if phase == "start":
+            self._open = self._annotation("python.gc",
+                                          generation=info["generation"])
+            self._open.__enter__()
+        elif self._open is not None:
+            self._open.__exit__(None, None, None)
+            self._open = None
+
+
+def _register_gc_span(annotation) -> None:
+    """Add the `python.gc` span to `gc.callbacks`, once per process."""
+    if not any(isinstance(cb, _GcSpan) for cb in gc.callbacks):
+        gc.callbacks.append(_GcSpan(annotation))
+
+
 @dataclasses.dataclass
 class _Bucket:
     key: tuple
@@ -173,7 +216,10 @@ class ConvServeEngine:
         self._buckets: Dict[tuple, _Bucket] = {}
         self._jit_cache: Dict[tuple, object] = {}
         self._next_uid = 0
-        self._latencies_us: List[float] = []
+        self._latencies_us: deque = deque(maxlen=LATENCY_WINDOW)
+        from jax.profiler import TraceAnnotation
+        self._span = TraceAnnotation
+        _register_gc_span(TraceAnnotation)
         self.stats: Dict[str, object] = {
             "submitted": 0, "completed": 0, "sheds": 0, "failures": 0,
             "retries": 0, "fallbacks": 0, "nan_events": 0,
@@ -313,22 +359,26 @@ class ConvServeEngine:
         from the front (slots refill from the queue on every launch),
         launch them through the degradation ladder, repeat."""
         results: Dict[int, np.ndarray] = {}
+        span = self._span
         while self._queue:
-            cohort, bucket = self._take_cohort()
-            if not cohort:
-                continue
-            out = self._launch(bucket, cohort)
-            if out is None:       # every rung failed for this cohort
-                self.stats["failures"] += len(cohort)
-                continue
-            now = time.monotonic()
-            for i, r in enumerate(cohort):
-                if r.deadline is not None and now > r.deadline:
-                    self.stats["deadline_misses"] += 1
+            with span("engine.cohort"):
+                with span("engine.take"):
+                    cohort, bucket = self._take_cohort()
+                if not cohort:
                     continue
-                self.stats["completed"] += 1
-                self._latencies_us.append((now - r.submitted) * 1e6)
-                results[r.uid] = out[i]
+                out = self._launch(bucket, cohort)
+                if out is None:       # every rung failed for this cohort
+                    self.stats["failures"] += len(cohort)
+                    continue
+                with span("engine.answer"):
+                    now = time.monotonic()
+                    for i, r in enumerate(cohort):
+                        if r.deadline is not None and now > r.deadline:
+                            self.stats["deadline_misses"] += 1
+                            continue
+                        self.stats["completed"] += 1
+                        self._latencies_us.append((now - r.submitted) * 1e6)
+                        results[r.uid] = out[i]
         return results
 
     def _take_cohort(self):
@@ -373,48 +423,58 @@ class ConvServeEngine:
         """One slot-batch launch through the ladder.  Returns the host
         output batch, or None when every rung (and the NaN retry budget)
         is exhausted."""
-        batch = np.zeros((self.slot_batch,) + bucket.payload_shape,
-                         np.float32)
-        for i, r in enumerate(cohort):
-            batch[i] = r.payload
-        self.stats["launches"] += 1
+        span = self._span
         n = len(cohort)
-        attempt = 0
-        rungs = self._rungs(bucket)
-        for ri, backend in enumerate(rungs):
-            breaker = bucket.breakers[backend]
-            probing = breaker.state == "half_open"
-            if probing:
-                self.stats["reprobes"] += 1
-            nan_budget = 1
-            while True:
-                if attempt > 0:
-                    self.stats["retries"] += 1
-                    self._backoff(attempt)
-                attempt += 1
-                try:
-                    ev = None
-                    if self.injector is not None:
-                        ev = self.injector.raise_or_delay(
-                            f"{bucket.kind}:{backend}")
-                    out = np.asarray(self._jitted(bucket, backend)(batch))
-                    if ev is not None:
-                        out = self.injector.poison(ev, out)
-                except Exception:  # noqa: BLE001 - ladder absorbs faults
-                    self.stats["kernel_faults"] += 1
-                    self._fail(breaker)
-                    break         # degrade: next rung serves this cohort
-                if not np.all(np.isfinite(out[:n])):
-                    self.stats["nan_events"] += 1
-                    if nan_budget > 0:
-                        nan_budget -= 1
-                        continue  # transient? one retry on the same rung
-                    self._fail(breaker)
-                    break         # systematic: degrade to the next rung
-                breaker.record_success()
-                if ri > 0:
-                    self.stats["fallbacks"] += 1
-                return out
+        with span("engine.launch", kind=bucket.kind, n=n,
+                  uid0=cohort[0].uid):
+            with span("engine.batch"):
+                batch = np.zeros((self.slot_batch,) + bucket.payload_shape,
+                                 np.float32)
+                for i, r in enumerate(cohort):
+                    batch[i] = r.payload
+            self.stats["launches"] += 1
+            attempt = 0
+            rungs = self._rungs(bucket)
+            for ri, backend in enumerate(rungs):
+                breaker = bucket.breakers[backend]
+                probing = breaker.state == "half_open"
+                if probing:
+                    self.stats["reprobes"] += 1
+                nan_budget = 1
+                while True:
+                    if attempt > 0:
+                        self.stats["retries"] += 1
+                        self._backoff(attempt)
+                    attempt += 1
+                    try:
+                        with span("engine.dispatch", rung=backend,
+                                  attempt=attempt):
+                            ev = None
+                            if self.injector is not None:
+                                ev = self.injector.raise_or_delay(
+                                    f"{bucket.kind}:{backend}")
+                            y = self._jitted(bucket, backend)(batch)
+                        with span("engine.fetch"):
+                            out = np.asarray(y)
+                        if ev is not None:
+                            out = self.injector.poison(ev, out)
+                    except Exception:  # noqa: BLE001 - ladder absorbs faults
+                        self.stats["kernel_faults"] += 1
+                        self._fail(breaker)
+                        break     # degrade: next rung serves this cohort
+                    with span("engine.check"):
+                        finite = bool(np.all(np.isfinite(out[:n])))
+                    if not finite:
+                        self.stats["nan_events"] += 1
+                        if nan_budget > 0:
+                            nan_budget -= 1
+                            continue  # transient? one retry on the same rung
+                        self._fail(breaker)
+                        break     # systematic: degrade to the next rung
+                    breaker.record_success()
+                    if ri > 0:
+                        self.stats["fallbacks"] += 1
+                    return out
         return None
 
     def _fail(self, breaker: CircuitBreaker) -> None:
@@ -433,7 +493,9 @@ class ConvServeEngine:
 
     def health(self) -> dict:
         """Stats snapshot plus latency percentiles and breaker states --
-        the surface a deployment scrapes."""
+        the surface a deployment scrapes.  `p50_us` / `p99_us` are taken
+        over the most recent `LATENCY_WINDOW` answers, each timed from
+        its `submit` to its answer on the host."""
         lat = np.asarray(self._latencies_us, np.float64)
         out = dict(self.stats)
         out["p50_us"] = float(np.percentile(lat, 50)) if lat.size else None
