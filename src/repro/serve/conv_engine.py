@@ -11,7 +11,10 @@ core of the design (DESIGN.md Sec. 2.11):
     through the models' `*_plan_requests` helpers, i.e. through
     `ConvSpec.make` -- into a bucket keyed by (workload kind, payload
     shape).  Each bucket owns compile-once jitted launch functions at a
-    fixed slot batch, so serving never recompiles per request.
+    fixed slot batch, so serving never recompiles per request.  A bucket
+    of large payloads (`DEVICE_SLOT_MIN_BYTES`) puts each request's
+    payload on the device by itself and makes its empty slots there;
+    smaller payloads cross as one zero-padded host slot batch.
   * **Bounded admission.**  Requests enter a bounded queue; submission
     beyond the bound is SHED (counted, rejected) rather than buffered
     without limit -- the engine can fall behind, it can never hang on an
@@ -51,8 +54,9 @@ nothing.  `engine.cohort` is one iteration of `run()`'s loop and
 holds `engine.take` (`_take_cohort`), `engine.launch` (the whole ladder;
 args `kind`, `n`, `uid0` = the uid of the cohort's first request) and
 `engine.answer` (deadline check, latency record, results).
-`engine.launch` holds `engine.batch` (the slot batch and its payload
-copies) and, per attempt, `engine.dispatch` (injector hook and the
+`engine.launch` holds `engine.batch` (the slot batch: its payload copies,
+or on the device-slot path the payloads' puts and the zero slots) and,
+per attempt, `engine.dispatch` (injector hook and the
 jitted call; args `rung`, `attempt`), `engine.fetch` (`np.asarray` of
 the output) and `engine.check` (the host `isfinite`).  Building the
 first engine also registers a `python.gc` span around every collection
@@ -62,6 +66,7 @@ from __future__ import annotations
 
 import dataclasses
 import gc
+import math
 import time
 from collections import deque
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -76,6 +81,15 @@ KINDS = ("gan_gen", "aspp")
 
 # `health()`'s latency percentiles cover this many most recent answers.
 LATENCY_WINDOW = 10_000
+
+# A bucket whose payload holds at least this many bytes puts each filled
+# slot on the device by itself and makes its empty slots there
+# (`_slots`), so no zero byte crosses the host link; smaller payloads
+# cross as one host slot batch, since one transfer of a few KB beats a
+# slot batch of tiny ones.  Set far from both payloads served here: a
+# GAN latent (512 B at z_dim 128) and a DeepLabv3 block-4 feature map
+# (33x33x2048 float32, 8.9 MB), so its exact value routes neither.
+DEVICE_SLOT_MIN_BYTES = 1 << 20
 
 
 @dataclasses.dataclass
@@ -176,6 +190,8 @@ class _Bucket:
     payload_shape: tuple
     specs: tuple              # the ConvSpec-normalized launch geometry
     breakers: Dict[str, CircuitBreaker]
+    device_slots: bool        # payloads cross one slot at a time (`_slots`)
+    zero_slot: object = None  # the empty slot, made on the device once
 
 
 class ConvServeEngine:
@@ -224,7 +240,7 @@ class ConvServeEngine:
             "submitted": 0, "completed": 0, "sheds": 0, "failures": 0,
             "retries": 0, "fallbacks": 0, "nan_events": 0,
             "deadline_misses": 0, "kernel_faults": 0, "quarantines": 0,
-            "reprobes": 0, "launches": 0, "warmup": None,
+            "reprobes": 0, "launches": 0, "h2d_bytes": 0, "warmup": None,
         }
 
     # -- buckets ----------------------------------------------------------
@@ -240,7 +256,9 @@ class ConvServeEngine:
             specs=tuple(e[1] for e in entries),
             breakers={name: CircuitBreaker(self.fail_threshold,
                                            self.cooldown)
-                      for name in self.ladder})
+                      for name in self.ladder},
+            device_slots=(np.dtype(np.float32).itemsize * math.prod(key[1])
+                          >= DEVICE_SLOT_MIN_BYTES))
         self._buckets[key] = b
         return b
 
@@ -281,13 +299,43 @@ class ConvServeEngine:
         raise ValueError(f"unknown request kind {kind!r}")
 
     def _jitted(self, bucket: _Bucket, backend: str):
+        """The bucket's compiled launch for `backend`, called with
+        `_slots`' arguments: on the device-slot path it stacks the
+        `slot_batch` slots inside the jit, so one program serves every
+        cohort size."""
         key = (bucket.key, backend)
         fn = self._jit_cache.get(key)
         if fn is None:
             import jax
-            fn = jax.jit(self.forward_fn(bucket.kind, backend))
+            fwd = self.forward_fn(bucket.kind, backend)
+            if bucket.device_slots:
+                import jax.numpy as jnp
+                fn = jax.jit(lambda *slots: fwd(jnp.stack(slots)))
+            else:
+                fn = jax.jit(fwd)
             self._jit_cache[key] = fn
         return fn
+
+    def _slots(self, bucket: _Bucket, payloads) -> tuple:
+        """The launch's arguments for `payloads`, the slots past them
+        empty.  Host path: one zero-padded float32 slot batch.
+        Device-slot path: each payload put on the device as float32 (its
+        bytes counted in `h2d_bytes`), every empty slot the bucket's zero
+        slot, made on the device and never transferred.  Nothing put here
+        outlives the launch: a payload crosses once per request."""
+        if not bucket.device_slots:
+            batch = np.zeros((self.slot_batch,) + bucket.payload_shape,
+                             np.float32)
+            for i, p in enumerate(payloads):
+                batch[i] = p
+            return (batch,)
+        import jax
+        if bucket.zero_slot is None:
+            import jax.numpy as jnp
+            bucket.zero_slot = jnp.zeros(bucket.payload_shape, jnp.float32)
+        put = jax.device_put([np.asarray(p, np.float32) for p in payloads])
+        self.stats["h2d_bytes"] += sum(a.nbytes for a in put)
+        return (*put,) + (bucket.zero_slot,) * (self.slot_batch - len(put))
 
     # -- warmup -----------------------------------------------------------
 
@@ -296,8 +344,8 @@ class ConvServeEngine:
         """Pre-plan every bucket's tiles from the shipped tile-cache
         artifact (never an autotune sweep; a corrupt artifact warns and
         falls back to the analytical planner) and optionally pre-compile
-        the primary rung with one dummy batch.  `shapes` lists
-        ``(kind, payload_shape)`` pairs."""
+        the primary rung's launch, called as `_launch` calls it, on empty
+        slots.  `shapes` lists ``(kind, payload_shape)`` pairs."""
         from repro.kernels import tiling
         from repro.kernels.ops import interpret_mode
         interpret = interpret_mode()
@@ -319,9 +367,8 @@ class ConvServeEngine:
         if compile:
             for kind, payload_shape in shapes:
                 bucket = self._bucket(kind, tuple(payload_shape))
-                batch = np.zeros((self.slot_batch,) + bucket.payload_shape,
-                                 np.float32)
-                np.asarray(self._jitted(bucket, self.ladder[0])(batch))
+                np.asarray(self._jitted(bucket, self.ladder[0])(
+                    *self._slots(bucket, [])))
         self.stats["warmup"] = summary
         return summary
 
@@ -422,16 +469,16 @@ class ConvServeEngine:
     def _launch(self, bucket: _Bucket, cohort) -> Optional[np.ndarray]:
         """One slot-batch launch through the ladder.  Returns the host
         output batch, or None when every rung (and the NaN retry budget)
-        is exhausted."""
+        is exhausted.  Every attempt reuses the slots built once: on the
+        device-slot path a retry sends nothing again, while a host slot
+        batch crosses (and is counted) with each jitted call."""
         span = self._span
         n = len(cohort)
         with span("engine.launch", kind=bucket.kind, n=n,
                   uid0=cohort[0].uid):
             with span("engine.batch"):
-                batch = np.zeros((self.slot_batch,) + bucket.payload_shape,
-                                 np.float32)
-                for i, r in enumerate(cohort):
-                    batch[i] = r.payload
+                slots = self._slots(bucket, [r.payload for r in cohort])
+            host_bytes = 0 if bucket.device_slots else slots[0].nbytes
             self.stats["launches"] += 1
             attempt = 0
             rungs = self._rungs(bucket)
@@ -453,7 +500,8 @@ class ConvServeEngine:
                             if self.injector is not None:
                                 ev = self.injector.raise_or_delay(
                                     f"{bucket.kind}:{backend}")
-                            y = self._jitted(bucket, backend)(batch)
+                            y = self._jitted(bucket, backend)(*slots)
+                            self.stats["h2d_bytes"] += host_bytes
                         with span("engine.fetch"):
                             out = np.asarray(y)
                         if ev is not None:

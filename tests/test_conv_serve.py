@@ -19,6 +19,7 @@ import jax.numpy as jnp
 
 from conftest import assert_allclose, count_pallas_calls
 from repro.models import gan, vision
+from repro.serve import conv_engine
 from repro.serve.conv_engine import (ConvRequest, ConvServeEngine,
                                      CircuitBreaker, DEFAULT_LADDER)
 from repro.serve.faults import (FaultEvent, FaultInjector, FaultSchedule,
@@ -26,6 +27,9 @@ from repro.serve.faults import (FaultEvent, FaultInjector, FaultSchedule,
 
 Z_DIM, BASE = 8, 8
 IMG = (8, 8, 3)
+IMG_BYTES = 4 * int(np.prod(IMG))          # a float32 ASPP payload
+HOST_SLOTS, DEVICE_SLOTS = 1 << 62, 1      # DEVICE_SLOT_MIN_BYTES per path
+SLOTS = 4
 
 
 @pytest.fixture(scope="module")
@@ -324,12 +328,141 @@ def test_bucket_normalizes_through_convspec(gan_params, aspp_params):
         eng._bucket("bogus", (1,))
 
 
-def test_warmup_pre_compiles_primary(gan_params):
-    eng = ConvServeEngine(gan_params=gan_params, slot_batch=2,
-                          queue_limit=4)
-    eng.warmup([("gan_gen", (Z_DIM,))], compile=True)
-    assert (("gan_gen", (Z_DIM,)), "pallas") in eng._jit_cache
-    assert eng.health()["warmup"]["buckets"] == 1
+def test_warmup_pre_compiles_primary(gan_params, rng, monkeypatch):
+    """On both slot paths, warmup compiles exactly the launch that serving
+    calls: a real launch adds no jit-cache entry and compiles nothing."""
+    for min_bytes in (HOST_SLOTS, DEVICE_SLOTS):
+        monkeypatch.setattr(conv_engine, "DEVICE_SLOT_MIN_BYTES", min_bytes)
+        eng = ConvServeEngine(gan_params=gan_params, slot_batch=2,
+                              queue_limit=4)
+        eng.warmup([("gan_gen", (Z_DIM,))], compile=True)
+        key = (("gan_gen", (Z_DIM,)), "pallas")
+        assert set(eng._jit_cache) == {key}
+        assert eng.health()["warmup"]["buckets"] == 1
+        assert eng._buckets[key[0]].device_slots == (min_bytes == 1)
+        compiled = eng._jit_cache[key]._cache_size()
+        assert len(eng.serve(_gan_reqs(rng, 1))) == 1
+        assert set(eng._jit_cache) == {key}
+        assert eng._jit_cache[key]._cache_size() == compiled
+
+
+# ---------------------------------------------------------------------------
+# Device-slot path: large payloads cross one slot at a time, the empty
+# slots are made on the device
+# ---------------------------------------------------------------------------
+
+def _serve_cohorts(monkeypatch, aspp_params, min_bytes, cohorts, **kw):
+    """Serve each cohort of payloads as one launch on a fresh engine whose
+    buckets route by `min_bytes`; returns the engine, {uid: answer} and
+    `h2d_bytes` after each launch."""
+    monkeypatch.setattr(conv_engine, "DEVICE_SLOT_MIN_BYTES", min_bytes)
+    eng = ConvServeEngine(aspp_params=aspp_params, slot_batch=SLOTS,
+                          queue_limit=16, **kw)
+    answers, h2d, uid = {}, [], 0
+    for payloads in cohorts:
+        for p in payloads:
+            eng.submit(ConvRequest(uid, "aspp", p))
+            uid += 1
+        answers.update(eng.run())
+        h2d.append(eng.stats["h2d_bytes"])
+    assert eng.stats["launches"] == len(cohorts)
+    return eng, answers, h2d
+
+
+def test_payload_size_picks_the_slot_path(aspp_params, monkeypatch):
+    assert 4 * 128 < conv_engine.DEVICE_SLOT_MIN_BYTES <= 4 * 33 * 33 * 2048
+    for min_bytes, device in ((IMG_BYTES, True), (IMG_BYTES + 1, False)):
+        monkeypatch.setattr(conv_engine, "DEVICE_SLOT_MIN_BYTES", min_bytes)
+        eng = ConvServeEngine(aspp_params=aspp_params, slot_batch=SLOTS)
+        assert eng._bucket("aspp", IMG).device_slots is device
+
+
+@pytest.mark.parametrize("n", [1, 2, SLOTS])
+def test_device_slots_answer_as_the_host_batch(aspp_params, rng,
+                                               monkeypatch, n):
+    """Bit-equal answers for a cohort of `n`; the device path puts only
+    the cohort's own payloads, the host path the whole slot batch."""
+    payloads = [rng.standard_normal(IMG).astype(np.float32)
+                for _ in range(n)]
+    _, host, host_h2d = _serve_cohorts(monkeypatch, aspp_params,
+                                       HOST_SLOTS, [payloads])
+    eng, dev, dev_h2d = _serve_cohorts(monkeypatch, aspp_params,
+                                       DEVICE_SLOTS, [payloads])
+    assert eng._buckets[("aspp", IMG)].device_slots
+    assert sorted(dev) == sorted(host) == list(range(n))
+    for uid in host:
+        assert np.array_equal(dev[uid], host[uid]), uid
+    assert host_h2d == [SLOTS * IMG_BYTES]
+    assert dev_h2d == [n * IMG_BYTES]
+
+
+@pytest.mark.parametrize("min_bytes,per_slot", [
+    (HOST_SLOTS, lambda n: SLOTS), (DEVICE_SLOTS, lambda n: n)],
+    ids=["host", "device"])
+def test_h2d_bytes_per_launch(aspp_params, rng, monkeypatch, min_bytes,
+                              per_slot):
+    sizes = [1, 3, SLOTS, 2]
+    cohorts = [[rng.standard_normal(IMG).astype(np.float32)
+                for _ in range(n)] for n in sizes]
+    _, answers, h2d = _serve_cohorts(monkeypatch, aspp_params, min_bytes,
+                                     cohorts)
+    assert len(answers) == sum(sizes)
+    assert np.diff([0] + h2d).tolist() == [per_slot(n) * IMG_BYTES
+                                          for n in sizes]
+
+
+@pytest.mark.parametrize("events,fallbacks", [
+    ([FaultEvent("aspp:pallas", 0, "nan_output")], 0),
+    ([FaultEvent("aspp:pallas", 0, "kernel_exception")], 1),
+    ([FaultEvent("aspp:pallas", 0, "nan_output"),
+      FaultEvent("aspp:pallas", 1, "nan_output")], 1)],
+    ids=["nan-retry", "exception-fallback", "nan-fallback"])
+def test_device_slot_retry_sends_nothing_again(aspp_params, rng,
+                                               monkeypatch, events,
+                                               fallbacks):
+    """A same-rung NaN retry and a fall to the next rung reuse the slots
+    already on the device: answers as the host path's under the same
+    faults, and `h2d_bytes` holds the cohort's payloads once."""
+    n = 2
+    payloads = [rng.standard_normal(IMG).astype(np.float32)
+                for _ in range(n)]
+    runs = {}
+    for min_bytes in (HOST_SLOTS, DEVICE_SLOTS):
+        inj = FaultInjector(FaultSchedule(list(events)))
+        runs[min_bytes] = _serve_cohorts(monkeypatch, aspp_params, min_bytes,
+                                         [payloads], injector=inj)
+    eng, dev, dev_h2d = runs[DEVICE_SLOTS]
+    _, host, _ = runs[HOST_SLOTS]
+    assert eng.stats["fallbacks"] == fallbacks
+    assert eng.stats["nan_events"] + eng.stats["kernel_faults"] == len(
+        events)
+    assert sorted(dev) == sorted(host) == list(range(n))
+    for uid in host:
+        assert np.all(np.isfinite(dev[uid]))
+        assert np.array_equal(dev[uid], host[uid]), uid
+    assert dev_h2d == [n * IMG_BYTES]
+
+
+def test_no_device_copy_outlives_its_request(aspp_params, rng,
+                                             monkeypatch):
+    """Two cohorts carrying the same payload object each put it: its
+    bytes count twice, and the engine keeps no device copy of it."""
+    put = jax.device_put
+    sent = []
+
+    def recording_put(x, *a, **kw):
+        sent.extend(np.shape(v) for v in jax.tree_util.tree_leaves(x))
+        return put(x, *a, **kw)
+
+    monkeypatch.setattr(jax, "device_put", recording_put)
+    p = rng.standard_normal(IMG).astype(np.float32)
+    eng, answers, h2d = _serve_cohorts(monkeypatch, aspp_params,
+                                       DEVICE_SLOTS, [[p], [p]])
+    assert h2d == [IMG_BYTES, 2 * IMG_BYTES]
+    assert sent.count(IMG) == 2
+    assert np.array_equal(answers[0], answers[1])
+    bucket = eng._buckets[("aspp", IMG)]
+    assert not np.any(np.asarray(bucket.zero_slot))
 
 
 # ---------------------------------------------------------------------------
