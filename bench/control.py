@@ -21,7 +21,7 @@ sys.path[0:1] = [str(ROOT), str(ROOT / "src")]
 
 import numpy as np  # noqa: E402
 
-from bench import harness, reference, serving, traffic  # noqa: E402
+from bench import harness, serving, traffic  # noqa: E402
 
 
 def main(argv) -> int:
@@ -40,7 +40,7 @@ def main(argv) -> int:
             print(f"control: {e}", file=sys.stderr)
             return 3
         model, tr = ctx.config["model"], ctx.traffic
-        pool = np.asarray(reference.payload_pool(
+        pool = np.asarray(harness.model_of(ctx).payload_pool(
             model, seed, int(tr["payload_pool"])))
         order = traffic.payload_order(tr, seed, args.requests)
         keep = serving.sample_mask(seed, args.requests,

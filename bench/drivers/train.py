@@ -26,22 +26,22 @@ CHECK_STEPS = 3
 class _Feed:
     """The trainer's data source: the benchmark's batches from the seed."""
 
-    def __init__(self, model: Dict, seed: int):
-        self.model, self.seed = model, seed
+    def __init__(self, mod, model: Dict, seed: int):
+        self.mod, self.model, self.seed = mod, model, seed
 
     def batch_at(self, step: int) -> Dict[str, np.ndarray]:
-        return reference.train_batch(self.model, self.seed, step)
+        return self.mod.train_batch(self.model, self.seed, step)
 
 
 def make_trainer(ctx: harness.Context, mesh=None):
     """The program's trainer over the benchmark's weights and feed."""
     from repro.parallel import sharding as sh
     from repro.train.conv_trainer import ConvTrainer, ConvTrainerConfig
-    model = ctx.config["model"]
+    model, mod = ctx.config["model"], harness.model_of(ctx)
 
     class Trainer(ConvTrainer):
         def init_state(self):
-            state = reference.gan_params(model, ctx.seed)
+            state = mod.gan_params(model, ctx.seed)
             if self.mesh is None:
                 return state
             with self.mesh, sh.use_mesh(self.mesh):
@@ -54,7 +54,7 @@ def make_trainer(ctx: harness.Context, mesh=None):
         batch=model["batch"], backend="pallas", lr=ctx.cell["lr"],
         guard=True, total_steps=1)
     tr = Trainer(tcfg, mesh=mesh)
-    tr.data = _Feed(model, ctx.seed)
+    tr.data = _Feed(mod, model, ctx.seed)
     return tr
 
 
@@ -90,18 +90,18 @@ def compare(ctx: harness.Context, p0, p1, p3, losses) -> Dict[str, float]:
     and the change of the weights over the three steps.  Leaves whose
     reference gradient is under a thousandth of the median leaf's move
     by round-off alone and are not counted."""
-    model = ctx.config["model"]
+    model, mod = ctx.config["model"], harness.model_of(ctx)
     num = reference.numerics(ctx.config)
     lr = float(ctx.cell["lr"])
-    step = jax.jit(lambda s, z, r: reference.gan_step(s, z, r, lr, num=num))
-    state = reference.gan_params(model, ctx.seed)
+    step = jax.jit(lambda s, z, r: mod.gan_step(s, z, r, lr, num=num))
+    state = mod.gan_params(model, ctx.seed)
     ref_losses, grads = [], None
     for k in range(CHECK_STEPS):
-        b = reference.train_batch(model, ctx.seed, k)
+        b = mod.train_batch(model, ctx.seed, k)
         state, gl, _, g = step(state, b["z"], b["real"])
         ref_losses.append(float(gl))
         grads = grads or _leaves(_host(g))
-    ref0 = _leaves(_host(reference.gan_params(model, ctx.seed)))
+    ref0 = _leaves(_host(mod.gan_params(model, ctx.seed)))
     ref3 = _leaves(_host(state))
     g_norm = {k: float(np.linalg.norm(v)) for k, v in grads.items()}
     med = float(np.median(list(g_norm.values())))
@@ -146,7 +146,7 @@ def run(ctx: harness.Context) -> harness.Outcome:
     mem = harness.memory_peak_bytes(ctx.devices)
     window_losses = [h["loss"] for h in out["history"][:CHECK_STEPS]]
     chips = len(ctx.devices)
-    step_ops = flops.gan_step_ops(model, model["batch"])
+    step_ops = harness.model_of(ctx).gan_step_ops(model, model["batch"])
     work = {"chips": chips, "peak_flops": ctx.peaks["flops_per_s"],
             "steps": steps, "useful_flops": steps * flops.total_flops(
                 step_ops), "batch": model["batch"]}

@@ -7,7 +7,9 @@ It builds the cell, warms up, measures for `ctx.seconds` (traced when
 `ctx.trace`), checks the timed path's answers against the reference and
 returns what it measured.  A per-layer metric's reader
 (`bench/metrics/<metric>.py`) exposes `read(outcome) -> float | None`;
-None leaves the metric out of the line.
+None leaves the metric out of the line.  A model kind's module
+(`bench/models/<kind>.py`, found by the configuration's `model.kind`)
+holds all that is particular to that kind.
 """
 from __future__ import annotations
 
@@ -15,6 +17,7 @@ import argparse
 import collections
 import contextlib
 import dataclasses
+import functools
 import importlib
 import importlib.util
 import json
@@ -30,6 +33,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 BENCH = pathlib.Path(__file__).resolve().parent
 ROOT = BENCH.parent
+MODELS = BENCH / "models"
 
 
 class NoChip(RuntimeError):
@@ -191,13 +195,40 @@ def profiled(enabled: bool, compiles: Optional[Dict[str, float]] = None):
         shutil.rmtree(d, ignore_errors=True)
 
 
-def load_reader(metric: str):
-    path = BENCH / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"bench.metrics.{metric.replace('.', '_')}", path)
+def _import_file(path: pathlib.Path, name: str) -> types.ModuleType:
+    """Import `path` as module `name`; a missing file raises
+    FileNotFoundError naming it."""
+    spec = importlib.util.spec_from_file_location(name, path)
     mod = importlib.util.module_from_spec(spec)
     spec.loader.exec_module(mod)
-    return mod.read
+    return mod
+
+
+def load_reader(metric: str):
+    return _import_file(BENCH / "metrics" / f"{metric}.py",
+                        f"bench.metrics.{metric.replace('.', '_')}").read
+
+
+def load_model(kind: str) -> types.ModuleType:
+    """The module of a configuration's `model.kind`, `MODELS/<kind>.py`.
+    A served kind's module gives `REQUEST_KIND` (the engine's request
+    kind), `TINY` (a configuration small enough for the CPU),
+    `params(model, seed)`, `payload_shape(model)`, `payload_pool(model,
+    seed, n)`, `engine_kwargs(model, params)` (what `ConvServeEngine` is
+    given), `forward(model, params, batch, num)` (the plain reference of
+    one launch) and `ops(model, batch)` (its `flops.Op` list).  Each
+    file is imported once a process."""
+    return _model_file(MODELS / f"{kind}.py")
+
+
+@functools.cache
+def _model_file(path: pathlib.Path) -> types.ModuleType:
+    return _import_file(path, f"bench.models.{path.stem.replace('.', '_')}")
+
+
+def model_of(ctx: Context) -> types.ModuleType:
+    """The module of the cell's configuration's model kind."""
+    return load_model(ctx.config["model"]["kind"])
 
 
 def device_record(ctx: Context, out: Outcome) -> Dict:

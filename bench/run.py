@@ -7,13 +7,14 @@
 Runs one cell of `BENCHMARK.json` on the chips of this machine: builds
 the cell's model from the seed, warms up every shape its traffic uses,
 measures for `--seconds`, checks what the timed path produced against
-the plain reference (`bench/reference.py`), and prints one JSON line as
-the last line of standard output.  `--trace 0` reports the cell's
-end-to-end metrics, `--trace 1` its per-layer metrics from a profiler
-trace of the window.  Everything is found by name: the cell in
+the plain reference (`bench/models/<kind>.py` over `bench/reference.py`),
+and prints one JSON line as the last line of standard output.
+`--trace 0` reports the cell's end-to-end metrics, `--trace 1` its
+per-layer metrics from a profiler trace of the window.  Everything is found by name: the cell in
 `bench/workloads/`, its configuration in `bench/configs/`, its traffic
-in `bench/traffic/`, its driver in `bench/drivers/` and each per-layer
-metric's reader in `bench/metrics/`.
+in `bench/traffic/`, its model kind's module in `bench/models/`, its
+driver in `bench/drivers/` and each per-layer metric's reader in
+`bench/metrics/`.
 
 Exits non-zero, printing no result, where JAX finds no TPU or fewer
 chips than the cell asks for.

@@ -18,11 +18,10 @@ import sys
 import time
 from typing import Dict, List, Sequence
 
+import jax
 import numpy as np
 
 from bench import flops, harness, reference
-
-KIND = {"dcgan": "gan_gen", "aspp": "aspp"}
 
 
 @dataclasses.dataclass
@@ -37,30 +36,23 @@ class Served:
     kept: Dict[int, np.ndarray] = dataclasses.field(default_factory=dict)
 
 
-def payload_shape(model: Dict) -> tuple:
-    if model["kind"] == "dcgan":
-        return (model["z_dim"],)
-    h, w = model["feature_hw"]
-    return (h, w, model["in_ch"])
-
-
 def build(ctx: harness.Context) -> Served:
     """Weights and payloads from the seed, the engine over them, and every
     shape the traffic uses compiled and run once."""
     from repro.serve.conv_engine import ConvRequest, ConvServeEngine
     model, eng_cfg = ctx.config["model"], ctx.cell["engine"]
     phase = _phases(ctx)
-    params = reference.serve_params(model, ctx.seed)
+    mod = harness.model_of(ctx)
+    params = mod.params(model, ctx.seed)
     phase("weights")
-    kw = ({"gan_params": params} if model["kind"] == "dcgan" else
-          {"aspp_params": params, "rates": tuple(model["rates"])})
     engine = ConvServeEngine(slot_batch=eng_cfg["slot_batch"],
                              queue_limit=eng_cfg["queue_limit"],
-                             ladder=tuple(eng_cfg["ladder"]), **kw)
-    kind = KIND[model["kind"]]
-    engine.warmup([(kind, payload_shape(model))], compile=True)
+                             ladder=tuple(eng_cfg["ladder"]),
+                             **mod.engine_kwargs(model, params))
+    kind = mod.REQUEST_KIND
+    engine.warmup([(kind, mod.payload_shape(model))], compile=True)
     phase("engine warm-up")
-    pool = np.asarray(reference.payload_pool(
+    pool = np.asarray(mod.payload_pool(
         model, ctx.seed, int(ctx.traffic["payload_pool"])))
     phase("payloads")
     # One real launch through the engine's whole host path.
@@ -68,7 +60,7 @@ def build(ctx: harness.Context) -> Served:
         engine.submit(ConvRequest(uid=i, kind=kind,
                                   payload=pool[i % len(pool)]))
     engine.run()
-    engine.stats.update(submitted=0, completed=0, launches=0)
+    engine.stats.update(submitted=0, completed=0, launches=0, h2d_bytes=0)
     phase("first launch")
     return Served(engine=engine, kind=kind, pool=pool,
                   slot_batch=engine.slot_batch,
@@ -121,6 +113,12 @@ def release(s: Served) -> None:
     gc.collect()
 
 
+def serve_forward(model: Dict, mod, num: reference.Numerics):
+    """`(params, batch) -> outputs` of one served launch, jitted: the
+    kind's reference forward at the numerics `num`."""
+    return jax.jit(lambda p, b: mod.forward(model, p, b, num))
+
+
 def check_answers(ctx: harness.Context, kept: Dict[int, np.ndarray],
                   payloads) -> Dict[str, float]:
     """The kept answers against the reference, worst request first:
@@ -128,9 +126,9 @@ def check_answers(ctx: harness.Context, kept: Dict[int, np.ndarray],
     `out_rms_err`, |answer - reference|_2 / |reference|_2.  `payloads(ids)`
     gives the requests' inputs.  The reference makes its weights again
     from the seed and runs in blocks of the engine's slot batch."""
-    model = ctx.config["model"]
-    params = reference.serve_params(model, ctx.seed)
-    fwd = reference.serve_forward(model, reference.numerics(ctx.config))
+    model, mod = ctx.config["model"], harness.model_of(ctx)
+    params = mod.params(model, ctx.seed)
+    fwd = serve_forward(model, mod, reference.numerics(ctx.config))
     ids = sorted(kept)
     block = int(ctx.cell["engine"]["slot_batch"])
     worst = {"out_err": 0.0, "out_rms_err": 0.0}
@@ -158,10 +156,10 @@ def control_error(ctx: harness.Context, ids: Sequence[int],
     """The control's reading: the reference computed in bfloat16, put in
     the program's place for requests `ids`, held to the same comparison
     as a run's answers."""
-    model = ctx.config["model"]
-    params = reference.serve_params(model, ctx.seed)
-    low = reference.serve_forward(
-        model, reference.numerics(ctx.config, control=True))
+    model, mod = ctx.config["model"], harness.model_of(ctx)
+    params = mod.params(model, ctx.seed)
+    low = serve_forward(model, mod,
+                        reference.numerics(ctx.config, control=True))
     block = int(ctx.cell["engine"]["slot_batch"])
     kept = {}
     for k in range(0, len(ids), block):
@@ -191,9 +189,9 @@ def work(ctx: harness.Context, s: Served, completed: int) -> Dict:
     """What the readers need about the work done: useful FLOPs of the
     completed requests, and the roofline time and count of the Pallas
     launches the window made (each launch runs a full slot batch)."""
-    model = ctx.config["model"]
-    per_req = flops.total_flops(flops.serve_ops(model, 1))
-    launch_ops = flops.serve_ops(model, s.slot_batch)
+    model, mod = ctx.config["model"], harness.model_of(ctx)
+    per_req = flops.total_flops(mod.ops(model, 1))
+    launch_ops = mod.ops(model, s.slot_batch)
     pallas = [o for o in launch_ops if o.kernel == "pallas"]
     launches = len(s.launch_s)
     return {"useful_flops": per_req * completed,

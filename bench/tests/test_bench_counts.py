@@ -5,7 +5,7 @@ from __future__ import annotations
 import numpy as np
 import pytest
 
-from bench import flops, traffic
+from bench import flops, harness, traffic
 
 
 @pytest.mark.parametrize("batch,o,k,cin,cout,stride,dilation", [
@@ -36,16 +36,17 @@ def test_tconv_macs_match_zero_free_mapping():
 
 def test_generator_and_aspp_counts():
     dcgan = {"kind": "dcgan", "z_dim": 100, "base": 256, "channels": 3}
-    ops = {o.name: o for o in flops.generator_ops(dcgan, 64)}
+    ops = {o.name: o for o in harness.load_model("dcgan").ops(dcgan, 64)}
     assert ops["t1"].flops == 2 * 64 * 16 * 16 * 256 * 512
     assert ops["t3"].flops == 2 * 64 * 256 * 16 * 3 * 128
     assert ops["proj"].kernel == "xla" and ops["t2"].kernel == "pallas"
     aspp = {"kind": "aspp", "feature_hw": [33, 33], "in_ch": 2048,
             "width": 256, "n_classes": 21, "rates": [6, 12, 18]}
-    total = flops.total_flops(flops.aspp_ops(aspp, 8))
+    aspp_ops = harness.load_model("aspp").ops
+    total = flops.total_flops(aspp_ops(aspp, 8))
     assert total == pytest.approx(247.1e9, rel=1e-3)
     peaks = {"flops_per_s": 197e12, "hbm_bytes_per_s": 819e9}
-    branch = flops.aspp_ops(aspp, 8)[0]
+    branch = aspp_ops(aspp, 8)[0]
     # compute-bound: the roofline time is the FLOP time
     assert flops.roofline_s([branch], peaks) == branch.flops / 197e12
 

@@ -2,9 +2,11 @@
 (`driver.run(ctx)`), on tiny configurations with the Pallas kernels in
 interpret mode: the timed path runs end to end and its answers are
 checked against the reference; the control, and the timed path broken
-underneath, come out as not correct."""
+underneath, come out as not correct.  Every model kind found in
+`bench/models/` is rehearsed at its module's `TINY` size."""
 from __future__ import annotations
 
+import shutil
 import time
 
 import jax
@@ -14,12 +16,10 @@ import pytest
 from bench import harness, serving
 from bench.drivers import serve_closed, serve_open
 
-TINY = {
-    "dcgan": {"kind": "dcgan", "z_dim": 8, "base": 16, "image": 32,
-              "channels": 3},
-    "aspp": {"kind": "aspp", "in_ch": 8, "feature_hw": [9, 9], "width": 8,
-             "rates": [1, 2, 3], "n_classes": 5},
-}
+# Served kinds: the modules under `MODELS` that give a request kind
+# (a name starting with `_` is a shared helper, not a kind).
+KINDS = sorted(p.stem for p in harness.MODELS.glob("[!_]*.py")
+               if hasattr(harness.load_model(p.stem), "REQUEST_KIND"))
 OPEN = {"loop": "open", "rate_per_s": 12.0, "payload_pool": 6}
 CLOSED = {"loop": "closed", "outstanding": 8, "payload_pool": 16}
 # On the CPU the kernels' float32 products are exact, so the program and
@@ -29,11 +29,12 @@ LIMIT = 1e-4
 
 def tiny_ctx(kind: str, traffic: dict, *, seconds: float = 1.0,
              seed: int = 2 ** 31 + 3) -> harness.Context:
+    model = dict(harness.load_model(kind).TINY, kind=kind)
     cell = {"config": f"tiny-{kind}", "traffic": "tiny", "chips": 1,
             "engine": {"slot_batch": 4, "queue_limit": 16,
                        "ladder": ["pallas"]},
             "check": {"share": 0.5, "out_err": LIMIT, "out_rms_err": LIMIT}}
-    config = {"model": TINY[kind],
+    config = {"model": model,
               "numerics": {"matmul_operands": "float32"}}
     return harness.Context(
         name=f"tiny-{kind}", cell=cell, config=config, traffic=traffic,
@@ -42,7 +43,7 @@ def tiny_ctx(kind: str, traffic: dict, *, seconds: float = 1.0,
         devices=jax.devices()[:1])
 
 
-@pytest.mark.parametrize("kind", ["dcgan", "aspp"])
+@pytest.mark.parametrize("kind", KINDS)
 def test_open_loop_runs_and_is_correct(kind):
     out = serve_open.run(tiny_ctx(kind, OPEN))
     assert out.correct, out.checks
@@ -54,7 +55,7 @@ def test_open_loop_runs_and_is_correct(kind):
     assert out.layer["answered"] == 12
 
 
-@pytest.mark.parametrize("kind", ["dcgan", "aspp"])
+@pytest.mark.parametrize("kind", KINDS)
 def test_closed_loop_runs_and_is_correct(kind):
     out = serve_closed.run(tiny_ctx(kind, CLOSED, seconds=0.5))
     assert out.correct, out.checks
@@ -64,18 +65,36 @@ def test_closed_loop_runs_and_is_correct(kind):
     assert out.layer["stats"]["completed"] == out.attempted
 
 
-@pytest.mark.parametrize("kind", ["dcgan", "aspp"])
+@pytest.mark.parametrize("kind", KINDS)
 def test_control_is_not_correct(kind):
     """The reference computed in bfloat16, in the program's place, reads
     far above the limit the program is held to."""
     ctx = tiny_ctx(kind, OPEN)
-    from bench import reference
-    pool = np.asarray(reference.payload_pool(TINY[kind], ctx.seed, 6))
+    assert_control_fails(ctx)
+
+
+def assert_control_fails(ctx):
+    model = ctx.config["model"]
+    pool = np.asarray(harness.model_of(ctx).payload_pool(model, ctx.seed, 6))
     order = np.arange(8) % 6
     errs = serving.control_error(ctx, list(range(8)),
                                  serving.payload_fn(pool, order))
     assert min(errs.values()) > 10 * LIMIT, errs
     assert not serving.verdict(serving.checks(ctx, errs, 0))
+
+
+def test_a_new_kind_needs_only_its_module(monkeypatch, tmp_path):
+    """A kind whose one file is its module under `MODELS` is served
+    through the open loop and held to its control, with no other file
+    of the benchmark naming it."""
+    kind = "aspp_copy"
+    shutil.copy(harness.MODELS / "aspp.py", tmp_path / f"{kind}.py")
+    monkeypatch.setattr(harness, "MODELS", tmp_path)
+    ctx = tiny_ctx(kind, OPEN)
+    out = serve_open.run(ctx)
+    assert out.correct, out.checks
+    assert out.layer["answered"] == 12 and out.failed == 0
+    assert_control_fails(ctx)
 
 
 def _break_engine(monkeypatch, how: str):

@@ -69,6 +69,9 @@ def test_every_cell_has_its_files_and_metrics():
             em = e2e[m["moves"]]
             assert "workloads" not in em or cell in em["workloads"], m
     assert {w["config"] for w in CELLS.values()} == set(configs)
+    for c in configs.values():
+        kind = json.loads((ROOT / c["file"]).read_text())["model"]["kind"]
+        assert (harness.MODELS / f"{kind}.py").is_file(), (c["name"], kind)
 
 
 def test_config_files_state_their_cuts():

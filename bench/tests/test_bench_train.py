@@ -12,8 +12,7 @@ import pytest
 from bench import harness, reference
 from bench.drivers import train
 
-TINY = {"kind": "dcgan", "z_dim": 8, "base": 16, "image": 32,
-        "channels": 3, "batch": 8}
+TINY = dict(harness.load_model("dcgan").TINY, batch=8)
 # float32 on the CPU: the program and the reference differ only by the
 # order of float32 sums.
 LIMITS = {"loss_err": 1e-4, "grad_err": 1e-3, "change_err": 1e-3}
