@@ -97,6 +97,30 @@ def _df_kernel(x_ref, w_ref, *refs, sh: int, sw: int, dh: int, dw: int,
             out_ref[0] = _tail(out_ref[0])
 
 
+def pack_strided(x: jax.Array, w: jax.Array, spec: ConvSpec, packed_shape):
+    """S x S space-to-depth of a strided, undilated forward conv whose
+    filter extents are multiples of the stride, into the `packed_shape`
+    (B, Hs, Ws, S_h*S_w*C) that `tiling.strided_forward_packing` gives:
+    the padded input xs[b, i, j, (p, q, c)] = xp[b, i*S_h + p, j*S_w + q, c]
+    and the filter ws[a, e, (p, q, c), o] = w[a*S_h + p, e*S_w + q, c, o],
+    so that the stride-1 (K/S) x (K/S) conv of xs with ws is the strided
+    conv of x with w, tap for tap."""
+    sh, sw = spec.stride
+    ph, pw = spec.padding
+    kh, kw = spec.filter_shape
+    B, hs, ws_, cs = packed_shape
+    C = x.shape[3]
+    # The padded frame holds at least hs*S_h rows ((O-1)*S + K <= N + 2P);
+    # rows past them feed no output.
+    xp = jnp.pad(x, ((0, 0), (ph, ph), (pw, pw), (0, 0)))
+    xp = xp[:, :hs * sh, :ws_ * sw, :]
+    xs = xp.reshape(B, hs, sh, ws_, sw, C).transpose(0, 1, 3, 2, 4, 5)
+    wp = w.reshape(kh // sh, sh, kw // sw, sw, C, w.shape[3])
+    wp = wp.transpose(0, 2, 1, 3, 4, 5)
+    return (xs.reshape(packed_shape),
+            wp.reshape(kh // sh, kw // sw, cs, w.shape[3]))
+
+
 @functools.partial(jax.jit, static_argnames=("stride", "padding", "dilation",
                                              "cin_tile", "cout_tile",
                                              "tap_unroll", "interpret",
@@ -115,7 +139,10 @@ def dconv_forward_pallas(x: jax.Array, w: jax.Array, *, stride=(1, 1),
     w: (Kh, Kw, Cin, Cout) undilated filter, applied at tap spacing D.
     Returns (B, Oh, Ow, Cout) with O = floor((N + 2P - K_eff)/S) + 1.
     Channel tiles default to the geometry-aware planner in
-    `kernels/tiling.py`; pass them explicitly to pin a tiling.
+    `kernels/tiling.py`; pass them explicitly to pin a tiling.  An
+    undilated strided conv whose filter extents are multiples of the
+    stride runs space-to-depth packed at stride 1 (`pack_strided`), and
+    its tiles are those of the packed problem.
 
     `epilogue` (an `Epilogue`, static) fuses act(scale * conv + bias)
     onto the resident output block before its HBM store; `bias` is the
@@ -137,6 +164,18 @@ def dconv_forward_pallas(x: jax.Array, w: jax.Array, *, stride=(1, 1),
         epilogue = None
     if epilogue is not None and epilogue.bias and bias is None:
         raise ValueError("epilogue.bias=True but no bias array was given")
+    packed, packed_shape = tiling.strided_forward_packing(spec, x.shape)
+    if packed is not spec:
+        # Mosaic takes a strided read only up to 128 lanes and holds the
+        # whole padded frame resident: run the stride-1 packed problem
+        # the planner planned instead (`tiling.strided_forward_packing`).
+        xs, ws = pack_strided(x, w, spec, packed_shape)
+        return dconv_forward_pallas(xs, ws, stride=(1, 1), padding=(0, 0),
+                                    dilation=(1, 1), bias=bias,
+                                    epilogue=epilogue, cin_tile=cin_tile,
+                                    cout_tile=cout_tile,
+                                    tap_unroll=tap_unroll,
+                                    interpret=interpret)
     if None in (cin_tile, cout_tile, tap_unroll):
         plan = tiling.plan_tiles("forward", spec, x_shape=x.shape,
                                  dy_shape=(B, Oh, Ow, Cout),

@@ -106,6 +106,7 @@ def tconv_phase(dy: jax.Array, w: jax.Array, *, stride, padding,
                               cout_tile=plan.cout_tile,
                               tap_unroll=plan.tap_unroll,
                               phase_unroll=plan.phase_unroll,
+                              spatial_tile=plan.spatial_tile,
                               interpret=interpret_mode())
 
 

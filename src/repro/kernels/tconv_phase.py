@@ -28,17 +28,19 @@ TPU mapping (the EcoFlow -> MXU translation, see DESIGN.md Sec. 2/2.5):
     sequentially over the (Cout-tile, tap) grid axes;
   * grouping/expansion onto the array becomes channel tiling.
 
-BlockSpec tiling: grid (B, T/pu, Cin_t, Cout_t, TK/u) with T = non-empty
-phases, TK = taps per phase (pu phases x u taps unroll per step --
-static window offsets when a single step remains); per grid step the
-kernel holds
-  dy block  (1, Hp, Wp, Co_t)     -- padded once; index map (b, co) only,
-                                     so it is NOT re-fetched across the
-                                     phase-local (tap) axis
-  w block   (pu, u, Co_t, Ci_t)   -- this step's packed (phase, tap)s
-  out block (1, pu, ho, wo, Ci_t) -- fp32 accumulator across (co, tap)
-in VMEM.  Neither block scales with full channel depth: dy carries a
-Cout tile and the output a Cin tile, with extents chosen per geometry by
+BlockSpec tiling: grid (B, T/pu, R, Cin_t, Cout_t, TK/u) with T = non-empty
+phases, R = row bands of each phase plane, TK = taps per phase (pu
+phases x u taps unroll per step -- static window offsets when a single
+step remains); per grid step the kernel holds
+  dy block  (1, Hp, Wp, Co_t)       -- padded once; index map (b, co)
+                                       only, so it is NOT re-fetched
+                                       across the band or tap axes
+  w block   (pu, u, Co_t, Ci_t)     -- this step's packed (phase, tap)s
+  out block (1, pu, band, wo, Ci_t) -- fp32 accumulator across (co, tap)
+in VMEM.  A band is the whole phase plane (R = 1) unless its window would
+pass the planner's compiled row cap (`tiling.phase_bands`).  Neither
+block scales with full channel depth: dy carries a Cout tile and the
+output a Cin tile, with extents chosen per geometry by
 `kernels/tiling.py` (DESIGN.md Sec. 2.6).  Output
 is phase-major (B, T, ho, wo, Cin); host-side assembly places each phase
 plane at its stride residue (a gather -- identity at D == 1) and
@@ -171,12 +173,14 @@ def _fused_tap_kernel(dy_ref, w_ref, *refs, tpw: int, kp: int, kq: int,
                       kh: int, kwf: int, per_h: int, per_w: int,
                       sh: int, sw: int, dh: int, dw: int, step_h: int,
                       step_w: int, pad_h: int, pad_w: int, ho: int, wo: int,
-                      pu: int, n_t: int, u: int, n_k: int, seq1: bool,
-                      ep=None):
+                      pu: int, n_t: int, u: int, n_k: int, n_sp: int,
+                      seq1: bool, ep=None):
     """`pu` phases x `u` taps per sequential grid step: read each tap's
     window out of the VMEM-resident padded dy block, one MXU
     matmul per tap with its (Cout_t, Cin_t) weights, accumulate each
-    phase's fp32 tile across the (Cout-tile, tap-step) axes.
+    phase's fp32 tile across the (Cout-tile, tap-step) axes.  A step
+    computes `ho` rows of each phase plane: band `program_id(2)` of
+    `n_sp` (the whole plane when n_sp == 1).
     When a single (phase, tap) grid step remains, every window offset is
     a python int and the reads are STATIC -- and the
     zero-padded slots of ragged phases (slot tap index kx >= K) are
@@ -191,18 +195,19 @@ def _fused_tap_kernel(dy_ref, w_ref, *refs, tpw: int, kp: int, kq: int,
     bias_ref = refs[0] if len(refs) == 2 else None
     out_ref = refs[-1]
     ts = pl.program_id(1) if n_t > 1 else 0
-    co = pl.program_id(3)
-    ks = pl.program_id(4) if n_k > 1 else 0
+    r0 = pl.program_id(2) * ho if n_sp > 1 else 0
+    co = pl.program_id(4)
+    ks = pl.program_id(5) if n_k > 1 else 0
     traced = n_t > 1 or n_k > 1
     # seq1: single sequential (Cout-tile, tap) step -> every visit to an
     # out block is its first, the predication compiles away.
     first = None if seq1 else (
-        (co == 0) if n_k == 1 else ((co == 0) & (pl.program_id(4) == 0)))
+        (co == 0) if n_k == 1 else ((co == 0) & (pl.program_id(5) == 0)))
     last = None
     if ep is not None and not seq1:
-        last = (co == pl.num_programs(3) - 1)
+        last = (co == pl.num_programs(4) - 1)
         if n_k > 1:
-            last &= pl.program_id(4) == n_k - 1
+            last &= pl.program_id(5) == n_k - 1
 
     def _tail(vals):
         return ep.apply(vals, None if bias_ref is None else bias_ref[0])
@@ -225,7 +230,8 @@ def _fused_tap_kernel(dy_ref, w_ref, *refs, tpw: int, kp: int, kq: int,
             # shifted into the padded frame.
             start_h = pad_h - (a * dh) // sh - (kp - 1 - uf) * step_h
             start_w = pad_w - (b * dw) // sw - (kq - 1 - vf) * step_w
-            win = read_window(dy_ref, (0,), start_h, start_w, oh=ho, ow=wo)
+            win = read_window(dy_ref, (0,), r0 + start_h, start_w, oh=ho,
+                              ow=wo)
             lhs = win.reshape(ho * wo, win.shape[-1]).astype(jnp.float32)
             rhs = w_ref[p, j].astype(jnp.float32)    # (co_t, ci_t)
             prod = jax.lax.dot(lhs, rhs,
@@ -252,8 +258,8 @@ def _fused_tap_kernel(dy_ref, w_ref, *refs, tpw: int, kp: int, kq: int,
 @functools.partial(jax.jit, static_argnames=("stride", "padding", "n_out",
                                              "dilation", "cin_tile",
                                              "cout_tile", "tap_unroll",
-                                             "phase_unroll", "interpret",
-                                             "epilogue"))
+                                             "phase_unroll", "spatial_tile",
+                                             "interpret", "epilogue"))
 def tconv_fused_pallas(dy: jax.Array, w: jax.Array, *, stride, padding=(0, 0),
                        n_out=None, dilation=(1, 1),
                        bias: jax.Array | None = None,
@@ -262,6 +268,7 @@ def tconv_fused_pallas(dy: jax.Array, w: jax.Array, *, stride, padding=(0, 0),
                        cout_tile: int | None = None,
                        tap_unroll: int | None = None,
                        phase_unroll: int | None = None,
+                       spatial_tile: int | None = None,
                        interpret: bool) -> jax.Array:
     """Zero-free transposed conv in a SINGLE `pallas_call`, any (S, D).
 
@@ -271,6 +278,8 @@ def tconv_fused_pallas(dy: jax.Array, w: jax.Array, *, stride, padding=(0, 0),
     Returns (B, Nh, Nw, Cin) where (Nh, Nw) = n_out (default exact fit).
     Channel tiles default to the geometry-aware planner in
     `kernels/tiling.py`; pass them explicitly to pin a tiling.
+    `spatial_tile` (dy rows) splits each phase plane into row bands
+    (`tiling.phase_bands`); None with pinned tiles keeps whole planes.
 
     `epilogue` (static `Epilogue`) fuses act(scale * . + bias) onto each
     finished phase plane in VMEM; `bias` is the (Cin,) vector (the tconv
@@ -297,14 +306,7 @@ def tconv_fused_pallas(dy: jax.Array, w: jax.Array, *, stride, padding=(0, 0),
     # (T, KP, KQ, Cout, Cin) -> flat tap axis for the (t, k) block index.
     w_flat = w_packed.reshape(T, TK, Cout, Cin)
 
-    # Pad dy ONCE (uniform across phases): front by the largest tap offset
-    # base(TPh-1) + (KP-1)*step, tail so every phase window of ho rows fits.
-    pad_h = spec.tap_phase_base(TPh - 1, 0) + (KP - 1) * step_h
-    pad_w = spec.tap_phase_base(TPw - 1, 1) + (KQ - 1) * step_w
     ho, wo = -(-Fh // sh), -(-Fw // sw)  # uniform phase-plane extent
-    dy_pad = jnp.pad(dy, ((0, 0), (pad_h, ho - Oh), (pad_w, wo - Ow),
-                          (0, 0)))
-    hp, wp = dy_pad.shape[1], dy_pad.shape[2]
 
     if epilogue is not None and epilogue.is_identity:
         epilogue = None
@@ -321,6 +323,16 @@ def tconv_fused_pallas(dy: jax.Array, w: jax.Array, *, stride, padding=(0, 0),
         tap_unroll = plan.tap_unroll if tap_unroll is None else tap_unroll
         phase_unroll = plan.phase_unroll if phase_unroll is None \
             else phase_unroll
+        spatial_tile = plan.spatial_tile if spatial_tile is None \
+            else spatial_tile
+    band, n_sp = tiling.phase_bands(Oh, ho, spatial_tile or Oh)
+    # Pad dy ONCE (uniform across phases): front by the largest tap offset
+    # base(TPh-1) + (KP-1)*step, tail so every band's windows fit.
+    pad_h = spec.tap_phase_base(TPh - 1, 0) + (KP - 1) * step_h
+    pad_w = spec.tap_phase_base(TPw - 1, 1) + (KQ - 1) * step_w
+    dy_pad = jnp.pad(dy, ((0, 0), (pad_h, n_sp * band - Oh),
+                          (pad_w, wo - Ow), (0, 0)))
+    hp, wp = dy_pad.shape[1], dy_pad.shape[2]
     ci_t = min(cin_tile, Cin)
     co_t = min(cout_tile, Cout)
     n_ci, n_co = -(-Cin // ci_t), -(-Cout // co_t)
@@ -339,13 +351,14 @@ def tconv_fused_pallas(dy: jax.Array, w: jax.Array, *, stride, padding=(0, 0),
                              kh=Kh, kwf=Kw, per_h=per_h, per_w=per_w,
                              sh=sh, sw=sw, dh=dh, dw=dw, step_h=step_h,
                              step_w=step_w, pad_h=pad_h, pad_w=pad_w,
-                             ho=ho, wo=wo, pu=pu, n_t=n_t, u=u, n_k=n_k,
+                             ho=band, wo=wo, pu=pu, n_t=n_t, u=u,
+                             n_k=n_k, n_sp=n_sp,
                              seq1=(n_co == 1 and n_k == 1), ep=epilogue)
     in_specs = [
         pl.BlockSpec((1, hp, wp, co_t),
-                     lambda b, t, ci, co, k: (b, 0, 0, co)),
+                     lambda b, t, r, ci, co, k: (b, 0, 0, co)),
         pl.BlockSpec((pu, u, co_t, ci_t),
-                     lambda b, t, ci, co, k: (t, k, co, ci)),
+                     lambda b, t, r, ci, co, k: (t, k, co, ci)),
     ]
     ins = [dy_pad, w_flat]
     if epilogue is not None and epilogue.bias:
@@ -353,15 +366,15 @@ def tconv_fused_pallas(dy: jax.Array, w: jax.Array, *, stride, padding=(0, 0),
         if Cin % ci_t:
             bp = jnp.pad(bp, ((0, 0), (0, n_ci * ci_t - Cin)))
         in_specs.append(pl.BlockSpec((1, ci_t),
-                                     lambda b, t, ci, co, k: (0, ci)))
+                                     lambda b, t, r, ci, co, k: (0, ci)))
         ins.append(bp)
     out = pl.pallas_call(
         kern,
-        grid=(B, n_t, n_ci, n_co, n_k),
+        grid=(B, n_t, n_sp, n_ci, n_co, n_k),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, pu, ho, wo, ci_t),
-                               lambda b, t, ci, co, k: (b, t, 0, 0, ci)),
-        out_shape=jax.ShapeDtypeStruct((B, T, ho, wo, n_ci * ci_t),
+        out_specs=pl.BlockSpec((1, pu, band, wo, ci_t),
+                               lambda b, t, r, ci, co, k: (b, t, r, 0, ci)),
+        out_shape=jax.ShapeDtypeStruct((B, T, n_sp * band, wo, n_ci * ci_t),
                                        jnp.float32),
         interpret=interpret,
         compiler_params=tiling.compiler_params(),
@@ -397,7 +410,8 @@ def _autotune_runner(spec: ConvSpec, x_shape, dy_shape, epilogue=None):
             dilation=spec.dilation, bias=bias, epilogue=epilogue,
             cin_tile=plan.cin_tile,
             cout_tile=plan.cout_tile, tap_unroll=plan.tap_unroll,
-            phase_unroll=plan.phase_unroll, interpret=interp))
+            phase_unroll=plan.phase_unroll, spatial_tile=plan.spatial_tile,
+            interpret=interp))
 
     return run
 

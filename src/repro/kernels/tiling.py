@@ -109,6 +109,13 @@ STEP_COST_COMPILED = 1 << 12
 # profits most from single-step launches, so it may unroll fully.
 MAX_TAP_UNROLL_COMPILED = 16
 
+# Compiled-mode cap on the rows (H x W sites) of one tap window of the
+# unified phase kernel: Mosaic unrolls each window's matmul and its
+# relayouts into vector ops, so code size and compile time grow with it.
+# A phase plane past the cap is computed in row bands (`phase_bands`);
+# the compile-time table is in PERF.md.
+MAX_WINDOW_ROWS_COMPILED = 1024
+
 OPS = ("filter_grad", "forward", "input_grad", "backward", "ct_backward")
 
 # Kernel strategies the planner races per geometry.  "phase" is the
@@ -142,6 +149,7 @@ class TilePlan:
         equal to them when the planner found the exact count cheapest,
         in which case the kernels skip the pad/slice entirely).
     spatial_tile -- output rows per block (Oh for the filter gradient;
+        dy rows per row band for the phase input gradient, `phase_bands`;
         kernels that do not spatially tile carry their full extent here).
     tap_unroll -- taps computed per grid step (a divisor of the tap
         count; 1 = one tap per step, T = all taps in one step).
@@ -208,6 +216,16 @@ def _spatial_candidates(oh: int) -> tuple[int, ...]:
     return tuple(dict.fromkeys(cands))
 
 
+def phase_bands(oh: int, ho: int, spatial_tile: int) -> tuple[int, int]:
+    """(band, n_bands) of the unified phase kernel: its `ho`-row phase
+    planes split into as many row bands as `spatial_tile` splits the
+    `oh` rows of dy, each band `band` rows (the last band may run past
+    `ho`; the assembly crops it).  `spatial_tile >= oh` is one band."""
+    n = _cdiv(oh, max(1, min(spatial_tile, oh)))
+    band = _cdiv(ho, n)
+    return band, _cdiv(ho, band)
+
+
 def _divisors(t: int) -> tuple[int, ...]:
     return tuple(d for d in range(t, 0, -1) if t % d == 0)
 
@@ -243,7 +261,28 @@ class _Geom:
     itemsize: int
 
 
+def strided_forward_packing(spec: ConvSpec, x_shape):
+    """The problem `dconv_forward` runs for a forward conv over
+    `x_shape`: `(spec, x_shape)` unchanged, or -- undilated, strided, and
+    each filter extent a multiple of its stride -- the S x S
+    space-to-depth form: a stride-1, unpadded (K/S) x (K/S) conv over
+    S_h*S_w*Cin channels of the (O + K/S - 1)-extent packed input, which
+    computes the same taps with no strided read (the forward's
+    counterpart of the transposed conv's phase decomposition)."""
+    sh, sw = spec.stride
+    kh, kw = spec.filter_shape
+    if spec.dilation != (1, 1) or (sh, sw) == (1, 1) or kh % sh or kw % sw:
+        return spec, x_shape
+    b, nh, nw, c = x_shape
+    oh, ow = spec.out_size((nh, nw))
+    kp, kq = kh // sh, kw // sw
+    return (ConvSpec.make(filter_shape=(kp, kq)),
+            (b, oh + kp - 1, ow + kq - 1, sh * sw * c))
+
+
 def _geom(op: str, spec: ConvSpec, x_shape, dy_shape, itemsize) -> _Geom:
+    if op == "forward":
+        spec, x_shape = strided_forward_packing(spec, x_shape)
     b, nh, nw, cin = x_shape
     _, oh, ow, cout = dy_shape
     return _Geom(spec, b, nh, nw, cin, oh, ow, cout, itemsize)
@@ -336,25 +375,29 @@ def _phase_frame(spec: ConvSpec, oh: int, ow: int):
 
 
 def _input_grad_model(g: _Geom, ci_t, co_t, sp_t, u, pu=1, ep=None):
-    """tconv_phase: grid (B, T/pu, Cin_t, Cout_t, TK/u); dy block holds
-    the full padded frame at a Cout tile, the w block `pu * u` packed
-    (phase, tap)s, the out block `pu` phase planes; out accumulates over
-    the sequential (Cout_t, tap-step) axes.  An epilogue with a bias adds
-    the (1, ci_t) bias-over-Cin block (the transposed conv's output
-    channels are the forward input channels)."""
+    """tconv_phase: grid (B, T/pu, R, Cin_t, Cout_t, TK/u); dy block
+    holds the full padded frame at a Cout tile, the w block `pu * u`
+    packed (phase, tap)s, the out block one row band of `pu` phase planes
+    (`phase_bands`: R bands for `sp_t` dy rows); out accumulates over the
+    sequential (Cout_t, tap-step) axes.  An epilogue with a bias adds the
+    (1, ci_t) bias-over-Cin block (the transposed conv's output channels
+    are the forward input channels)."""
     t, tk, ho, wo, hp, wp = _phase_frame(g.spec, g.oh, g.ow)
+    band, n_sp = phase_bands(g.oh, ho, sp_t)
+    hp += n_sp * band - ho       # dy tail-padded for the last band
     n_ci, n_co = _cdiv(g.cin, ci_t), _cdiv(g.cout, co_t)
     dy_blk = hp * wp * co_t * g.itemsize
     w_blk = pu * u * co_t * ci_t * g.itemsize
-    out_blk = pu * ho * wo * ci_t * 4
-    ws = 2 * (dy_blk + w_blk) + out_blk + ho * wo * co_t * 4
-    traffic = (g.b * _cdiv(t, pu) * n_ci * n_co * dy_blk
-               + g.b * t * tk * n_ci * n_co * co_t * ci_t * g.itemsize
-               + g.b * t * ho * wo * n_ci * ci_t * 4)
+    out_blk = pu * band * wo * ci_t * 4
+    ws = 2 * (dy_blk + w_blk) + out_blk + band * wo * co_t * 4
+    traffic = (g.b * _cdiv(t, pu) * n_sp * n_ci * n_co * dy_blk
+               + g.b * t * tk * n_sp * n_ci * n_co * co_t * ci_t
+               * g.itemsize
+               + g.b * t * n_sp * band * wo * n_ci * ci_t * 4)
     if ep is not None and ep.bias:
         ws += 2 * ci_t * 4
         traffic += n_ci * ci_t * 4
-    steps = g.b * _cdiv(t, pu) * n_ci * n_co * _cdiv(tk, u)
+    steps = g.b * _cdiv(t, pu) * n_sp * n_ci * n_co * _cdiv(tk, u)
     return ws, traffic, steps, dy_blk + w_blk
 
 
@@ -509,8 +552,10 @@ def _candidates(op: str, g: _Geom, strategy: str, interpret: bool):
     tap-axis extent: Kh*Kw for the tap-on-grid kernels (including the
     implicit-GEMM flat-tap grid), KP*KQ packed taps per phase for the
     unified phase input gradient -- whose phase axis additionally unrolls
-    by `pu` (a divisor of the non-empty phase count).  Only the
-    filter-grad grid spatially tiles.  Compiled unrolls cover whole tap
+    by `pu` (a divisor of the non-empty phase count).  The filter-grad
+    grid spatially tiles; the compiled phase input gradient splits its
+    planes into row bands only past `MAX_WINDOW_ROWS_COMPILED`
+    (`_band_tile`).  Compiled unrolls cover whole tap
     (and phase) rows, and compiled implicit-GEMM unrolls every tap: its
     windows are slices of an in-register frame, which Mosaic can only
     take at static offsets."""
@@ -520,6 +565,8 @@ def _candidates(op: str, g: _Geom, strategy: str, interpret: bool):
     co_cands = _channel_candidates(g.cout, interpret)
     sp_cands = _spatial_candidates(g.oh) if op == "filter_grad" \
         else (g.oh,)
+    if op == "input_grad" and strategy == "phase" and not interpret:
+        sp_cands = (_band_tile(g),)
     if op in ("input_grad", "backward") and strategy == "phase":
         kp, kq = g.spec.taps_per_phase
         tph, tpw = g.spec.n_tap_phases
@@ -547,6 +594,18 @@ def _candidates(op: str, g: _Geom, strategy: str, interpret: bool):
                 for u in u_cands:
                     for pu in pu_cands:
                         yield ci_t, co_t, sp_t, u, pu
+
+
+def _band_tile(g: _Geom) -> int:
+    """dy rows per band of the compiled phase input gradient: the whole
+    plane (`g.oh`) when a tap window of it stays within
+    `MAX_WINDOW_ROWS_COMPILED` sites, else the largest halving of `g.oh`
+    whose band does."""
+    _, _, ho, wo, _, _ = _phase_frame(g.spec, g.oh, g.ow)
+    for sp in _spatial_candidates(g.oh):
+        if phase_bands(g.oh, ho, sp)[0] * wo <= MAX_WINDOW_ROWS_COMPILED:
+            return sp
+    return 1
 
 
 def _working_set(op: str, g: _Geom, ci_t, co_t, sp_t, u, pu, interpret,

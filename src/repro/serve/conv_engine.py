@@ -43,9 +43,10 @@ core of the design (DESIGN.md Sec. 2.11):
 Fault injection (`serve/faults.py`) hooks the launch path OUTSIDE jit:
 launch-class events fire before the jitted call, output-class events
 poison the host-materialized result.  With no injector attached the
-fast path is a plain jitted `generator_apply` / `atrous_head_apply`
-with `backend="pallas"` -- exactly ONE forward `pallas_call` per conv
-layer, same as the training stack (the jaxpr pins hold unmodified).
+fast path is a plain jitted `generator_apply` / `atrous_head_apply` /
+`unet_apply` with `backend="pallas"` -- exactly ONE forward `pallas_call`
+per conv layer, same as the training stack (the jaxpr pins hold
+unmodified).
 
 Tracing.  The serving loop writes `jax.profiler.TraceAnnotation` spans,
 one set per cohort (never per request), into the profiler's own trace,
@@ -77,7 +78,7 @@ from repro.serve.faults import FaultInjector
 
 DEFAULT_LADDER = ("pallas", "xla_zero_free", "reference")
 
-KINDS = ("gan_gen", "aspp")
+KINDS = ("gan_gen", "aspp", "unet")
 
 # `health()`'s latency percentiles cover this many most recent answers.
 LATENCY_WINDOW = 10_000
@@ -86,9 +87,11 @@ LATENCY_WINDOW = 10_000
 # slot on the device by itself and makes its empty slots there
 # (`_slots`), so no zero byte crosses the host link; smaller payloads
 # cross as one host slot batch, since one transfer of a few KB beats a
-# slot batch of tiny ones.  Set far from both payloads served here: a
-# GAN latent (512 B at z_dim 128) and a DeepLabv3 block-4 feature map
-# (33x33x2048 float32, 8.9 MB), so its exact value routes neither.
+# slot batch of tiny ones.  Set far from both payloads it was chosen
+# between: a GAN latent (512 B at z_dim 128) and a DeepLabv3 block-4
+# feature map (33x33x2048 float32, 8.9 MB), so its exact value routes
+# neither.  A pix2pix image (256x256x3 float32, 786,432 B) takes the
+# host path.
 DEVICE_SLOT_MIN_BYTES = 1 << 20
 
 
@@ -96,7 +99,8 @@ DEVICE_SLOT_MIN_BYTES = 1 << 20
 class ConvRequest:
     """One inference request.
 
-    kind       -- "gan_gen" (payload: a (z_dim,) latent) or "aspp"
+    kind       -- "gan_gen" (payload: a (z_dim,) latent), "aspp"
+                  (payload: an (H, W, C) feature map) or "unet"
                   (payload: an (H, W, C) image).
     deadline_s -- optional deadline RELATIVE to submission; the absolute
                   deadline is stamped by `submit`.  An expired request is
@@ -195,13 +199,15 @@ class _Bucket:
 
 
 class ConvServeEngine:
-    """Continuous-batching request manager over the GAN generator and
-    the ASPP atrous head.  Single-threaded and synchronous by design
-    (the edge-serving regime this models has one accelerator): `submit`
-    admits or sheds, `run` drains the queue, `serve` does both."""
+    """Continuous-batching request manager over the GAN generator, the
+    ASPP atrous head and the pix2pix U-Net generator.  Single-threaded
+    and synchronous by design (the edge-serving regime this models has
+    one accelerator): `submit` admits or sheds, `run` drains the queue,
+    `serve` does both."""
 
     def __init__(self, *, gan_params=None, aspp_params=None,
-                 slot_batch: int = 4, queue_limit: int = 32,
+                 unet_params=None, slot_batch: int = 4,
+                 queue_limit: int = 32,
                  ladder: Sequence[str] = DEFAULT_LADDER,
                  injector: Optional[FaultInjector] = None,
                  fail_threshold: int = 2, cooldown: int = 3,
@@ -216,6 +222,7 @@ class ConvServeEngine:
             raise ValueError("ladder must name at least one backend")
         self.gan_params = gan_params
         self.aspp_params = aspp_params
+        self.unet_params = unet_params
         self.slot_batch = int(slot_batch)
         self.queue_limit = int(queue_limit)
         self.ladder = tuple(ladder)
@@ -279,6 +286,12 @@ class ConvServeEngine:
             return vision.atrous_plan_requests(
                 self.aspp_params, (self.slot_batch,) + payload_shape,
                 rates=self.rates, fuse_epilogue=self.fuse_epilogue)
+        if kind == "unet":
+            if self.unet_params is None:
+                raise ValueError("no unet_params: cannot serve unet")
+            from repro.models import unet
+            return unet.unet_plan_requests(
+                self.unet_params, (self.slot_batch,) + payload_shape)
         raise ValueError(f"unknown request kind {kind!r}; "
                          f"expected one of {KINDS}")
 
@@ -296,6 +309,10 @@ class ConvServeEngine:
             return lambda batch: vision.atrous_head_apply(
                 self.aspp_params, batch, rates=self.rates,
                 backend=backend, fuse_epilogue=self.fuse_epilogue)
+        if kind == "unet":
+            from repro.models import unet
+            return lambda batch: unet.unet_apply(self.unet_params, batch,
+                                                 backend=backend)
         raise ValueError(f"unknown request kind {kind!r}")
 
     def _jitted(self, bucket: _Bucket, backend: str):

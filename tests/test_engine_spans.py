@@ -144,3 +144,16 @@ def test_health_percentiles_cover_the_latest_answers(gan_params, rng,
     assert h["completed"] == 6
     assert h["p50_us"] == pytest.approx(2.5)
     assert h["p99_us"] == pytest.approx(3.97)
+
+
+def test_a_unet_cohort_is_annotated_with_its_kind(rng, raw):
+    from repro.models import unet
+    params = unet.unet_init(jax.random.PRNGKey(1), num_downs=5, ngf=4)
+    eng = ConvServeEngine(unet_params=params, slot_batch=2, queue_limit=4,
+                          ladder=("pallas",))
+    img = rng.uniform(-1, 1, (32, 32, 3)).astype(np.float32)
+    with harness.profiled(True):
+        eng.submit(ConvRequest(7, "unet", img))
+        assert len(eng.run()) == 1
+    assert [(a["kind"], a["n"], a["uid0"]) for a in
+            span_args(raw["pd"], "engine.launch")] == [("unet", 1, 7)]

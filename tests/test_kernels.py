@@ -9,7 +9,8 @@ import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.core import ecoflow
-from repro.kernels import ops, ref
+from repro.core.spec import Epilogue
+from repro.kernels import ops, ref, tiling
 from repro.kernels.attention import flash_attention_pallas
 from repro.kernels.dconv_filtergrad import dconv_filter_grad_pallas
 from repro.kernels.dconv_forward import dconv_forward_pallas
@@ -114,8 +115,9 @@ def test_tconv_cout_tiled_dy_block(rng):
         cout_tile=tile, cin_tile=4, tap_unroll=1, interpret=True)
     grids = _pallas_grids(fn, dy, w)
     assert len(grids) == 1
-    # grid (B, T, Cin_t, Cout_t, TK): sequential Cout axis of ceil(Co/tile).
-    assert grids[0][3] == -(-Co // tile), grids[0]
+    # grid (B, T, R, Cin_t, Cout_t, TK): sequential Cout axis of
+    # ceil(Co/tile); one row band R.
+    assert grids[0][4] == -(-Co // tile) and grids[0][2] == 1, grids[0]
     blocks = pallas_block_shapes(fn, dy, w)[0]
     dy_block, w_block, out_block = blocks
     assert dy_block[-1] == tile, blocks        # dy: Cout tile, not Co
@@ -125,6 +127,29 @@ def test_tconv_cout_tiled_dy_block(rng):
     want = ref.tconv_phase_ref(dy, w, stride=(S, S), padding=(P, P),
                                n_out=(N, N))
     assert_allclose(out, want, rtol=1e-4, atol=1e-4)
+
+
+@pytest.mark.parametrize("O,sp,bands", [(8, 4, 2), (8, 3, 3), (7, 2, 4),
+                                         (8, 1, 5), (8, 8, 1)])
+def test_tconv_row_bands(rng, O, sp, bands):
+    """Phase planes computed in row bands (`spatial_tile` dy rows a
+    band; the last band may run past the plane) answer as whole planes
+    do, fused bias and tanh included."""
+    from repro.core.spec import Epilogue
+    Ci, Co = 3, 5
+    ep = Epilogue(activation="tanh", bias=True)
+    dy = jnp.asarray(rng.normal(size=(2, O, O, Co)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(4, 4, Ci, Co)), jnp.float32)
+    b = jnp.asarray(rng.normal(size=(Ci,)), jnp.float32)
+    fn = lambda dy_, w_: tconv_fused_pallas(
+        dy_, w_, stride=(2, 2), padding=(1, 1), n_out=(2 * O, 2 * O),
+        bias=b, epilogue=ep, cin_tile=Ci, cout_tile=Co, tap_unroll=4,
+        phase_unroll=4, spatial_tile=sp, interpret=True)
+    assert _pallas_grids(fn, dy, w)[0][2] == bands
+    want = ep.apply(ref.tconv_phase_ref(dy, w, stride=(2, 2),
+                                        padding=(1, 1),
+                                        n_out=(2 * O, 2 * O)), b)
+    assert_allclose(fn(dy, w), want, rtol=1e-4, atol=1e-4)
 
 
 @pytest.mark.parametrize("S", [2, 3, 4])
@@ -356,6 +381,73 @@ def test_dconv_forward_cin_tiled(rng):
     want = ref.dconv_forward_ref(x, w, stride=(S, S), padding=(P, P),
                                  dilation=(D, D))
     assert_allclose(y, want, rtol=1e-4, atol=1e-4)
+
+
+LEAKY_BIAS = Epilogue(activation="leaky_relu", slope=0.2, bias=True)
+
+
+@pytest.mark.parametrize("N", [16, 17, 9, 10])
+@pytest.mark.parametrize("ep", [None, Epilogue(activation="relu"),
+                                LEAKY_BIAS])
+def test_strided_forward_packed_parity(rng, N, ep):
+    """K4 S2 P1 at even and odd sizes, with and without a fused bias and
+    activation: the space-to-depth packed launch against
+    `lax.conv_general_dilated` at highest precision."""
+    Ci, Co = 3, 5
+    x = jnp.asarray(rng.normal(size=(2, N, N, Ci)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(4, 4, Ci, Co)), jnp.float32)
+    b = jnp.asarray(rng.normal(size=(Co,)), jnp.float32)
+    bias = b if ep is not None and ep.bias else None
+    with jax.default_matmul_precision("highest"):
+        want = jax.lax.conv_general_dilated(
+            x, w, (2, 2), [(1, 1)] * 2,
+            dimension_numbers=("NHWC", "HWIO", "NHWC"))
+        if ep is not None:
+            want = ep.apply(want, bias)
+        y = ops.dconv_forward(x, w, stride=(2, 2), padding=(1, 1),
+                              dilation=(1, 1), bias=bias, epilogue=ep)
+    assert y.shape == want.shape
+    assert_allclose(y, want, rtol=1e-5, atol=1e-5)
+
+
+def test_strided_forward_runs_packed_at_stride_one(rng):
+    """The K4 S2 launch reads a 2x2-packed, stride-1 problem: its input
+    block carries 4 x Cin channels over (O + 1) rows, its grid 2 x 2
+    taps; the planner plans that same problem."""
+    from repro.core.spec import ConvSpec
+    B, N, Ci, Co = 2, 16, 3, 5
+    x = jnp.asarray(rng.normal(size=(B, N, N, Ci)), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(4, 4, Ci, Co)), jnp.float32)
+    fn = lambda x_, w_: dconv_forward_pallas(
+        x_, w_, stride=(2, 2), padding=(1, 1), dilation=(1, 1),
+        interpret=True)
+    (x_block, w_block, _), = pallas_block_shapes(fn, x, w)
+    assert x_block == (1, 9, 9, 4 * Ci) and w_block[-2:] == (4 * Ci, Co)
+    spec = ConvSpec.make(stride=2, padding=1, filter_shape=4)
+    packed, shape = tiling.strided_forward_packing(spec, x.shape)
+    assert packed == ConvSpec.make(filter_shape=2)
+    assert shape == (B, 9, 9, 12)
+
+
+@pytest.mark.parametrize("S,K,D", [(1, 3, 6), (1, 3, 12), (2, 3, 1),
+                                   (4, 11, 1), (2, 4, 2)])
+def test_strided_packing_leaves_other_geometries(rng, S, K, D):
+    """Only undilated strided convs whose filter is a multiple of the
+    stride are packed: ASPP's dilated rates and K % S != 0 keep the
+    padded-frame kernel, block for block."""
+    from repro.core.spec import ConvSpec
+    spec = ConvSpec.make(stride=S, padding=D, filter_shape=K, dilation=D)
+    shape = (1, 33, 33, 8)
+    assert tiling.strided_forward_packing(spec, shape) == (spec, shape)
+    x = jnp.asarray(rng.normal(size=shape), jnp.float32)
+    w = jnp.asarray(rng.normal(size=(K, K, 8, 4)), jnp.float32)
+    fn = lambda x_, w_: dconv_forward_pallas(
+        x_, w_, stride=(S, S), padding=(D, D), dilation=(D, D),
+        interpret=True)
+    (x_block, _, _), = pallas_block_shapes(fn, x, w)
+    oh = spec.out_size((33, 33))[0]
+    assert x_block[1] >= (oh - 1) * S + D * (K - 1) + 1
+    assert x_block[-1] == 8
 
 
 def test_dconv_forward_bf16(rng):

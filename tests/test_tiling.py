@@ -9,7 +9,7 @@ import json
 
 import pytest
 
-from repro.core.spec import ConvSpec
+from repro.core.spec import ConvSpec, Epilogue
 from repro.kernels import tiling
 
 
@@ -635,3 +635,61 @@ def test_analytical_race_crossover_on_bench_geometries():
         picks = {name: race(L, interpret) for name, L in layers.items()}
         assert picks["alexnet-CONV1"] == "phase", picks
         assert "implicit_gemm" in picks.values(), picks
+
+
+_RELU = Epilogue(activation="relu")
+_K4S2 = ConvSpec.make(stride=2, padding=1, filter_shape=4)
+
+
+@pytest.mark.parametrize("name,op,spec,x_shape,dy_shape,ep,want", [
+    # dcgan32's generator at slot batch 64: (cin, cout, spatial, taps,
+    # phases)
+    ("t1", "input_grad", _K4S2, (64, 8, 8, 256), (64, 4, 4, 512), _RELU,
+     (256, 256, 4, 2, 4)),
+    ("t2", "input_grad", _K4S2, (64, 16, 16, 128), (64, 8, 8, 256), _RELU,
+     (128, 256, 8, 4, 4)),
+    ("t3", "input_grad", _K4S2, (64, 32, 32, 3), (64, 16, 16, 128),
+     Epilogue(activation="tanh"), (3, 128, 16, 4, 4)),
+] + [
+    # the ASPP head's three atrous branches at slot batch 8
+    (f"rate{r}", "forward",
+     ConvSpec.make(stride=1, padding=r, filter_shape=3, dilation=r),
+     (8, 33, 33, 2048), (8, 33, 33, 256), _RELU,
+     (128, 256, 33, 9 if r < 18 else 3, 1)) for r in (6, 12, 18)])
+def test_benchmark_cells_keep_their_plans(name, op, spec, x_shape, dy_shape,
+                                          ep, want):
+    """The compiled plans of the benchmark cells' launches, as they were
+    before the strided packing and the phase kernel's row bands."""
+    strategy, plan = tiling.plan_strategy(op, spec, x_shape=x_shape,
+                                          dy_shape=dy_shape,
+                                          interpret=False, epilogue=ep)
+    assert strategy == "phase"
+    assert (plan.cin_tile, plan.cout_tile, plan.spatial_tile,
+            plan.tap_unroll, plan.phase_unroll) == want
+
+
+@pytest.mark.parametrize("o,cin,cout,bands", [
+    (128, 128, 3, 26), (64, 256, 64, 8), (32, 512, 128, 2),
+    (16, 1024, 256, 1)])
+def test_phase_planes_past_the_row_cap_run_in_bands(o, cin, cout, bands):
+    """U-Net-256's last transposed convs: compiled, a phase plane whose
+    tap window would pass `MAX_WINDOW_ROWS_COMPILED` sites runs in row
+    bands under it; below the cap, and in interpret mode, planes stay
+    whole."""
+    kw = dict(x_shape=(16, 2 * o, 2 * o, cout), dy_shape=(16, o, o, cin))
+    plan = tiling.plan_tiles("input_grad", _K4S2, interpret=False, **kw)
+    ho = o + 1                                   # phase-plane rows
+    band, n = tiling.phase_bands(o, ho, plan.spatial_tile)
+    assert n == bands and n * band >= ho > (n - 1) * band
+    assert band * ho <= tiling.MAX_WINDOW_ROWS_COMPILED
+    assert tiling.plan_tiles("input_grad", _K4S2, interpret=True,
+                             **kw).spatial_tile == o
+
+
+def test_forward_plans_see_the_packed_problem():
+    """A strided forward is planned as the stride-1 space-to-depth
+    problem it launches: the 3-channel stem tiles 12 packed channels and
+    unrolls at most its 2 x 2 taps."""
+    plan = tiling.plan_tiles("forward", _K4S2, x_shape=(16, 256, 256, 3),
+                             dy_shape=(16, 128, 128, 64), interpret=False)
+    assert plan.cin_tile == 12 and plan.tap_unroll in (2, 4)

@@ -5,7 +5,9 @@ step) with `interpret=False` against a described `v5e:2x2` topology and
 lets Mosaic and XLA compile it, which raises what the chip's compiler
 would refuse -- unaligned blocks, value-level gathers, VMEM overruns.
 Shapes are the GAN's default widths (z_dim 64, base 64, 32x32 images,
-batch 128) and the default ASPP head at 128x128x3.
+batch 128), its training step also at dcgan32's widths, the default ASPP
+head at 128x128x3, and every layer of pix2pix's U-Net-256 at slot batch
+16.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the test workers all import
@@ -107,6 +109,41 @@ def test_kernel_compiles_for_v5e(name, one_chip, compiled_mode):
     _compile(fn, *args)
 
 
+# pix2pix U-Net-256 at slot batch 16: each of its 16 layer geometries as
+# the engine launches it, fused epilogue included -- the 256x256x3 stem
+# (space-to-depth packed), the 512-wide strided convs, and the last
+# transposed conv, whose 129-row phase planes run in row bands.
+UB = 16
+UNET = [3, 64, 128, 256, 512, 512, 512, 512, 512]
+
+
+def _unet_layer(name):
+    k = int(name[-1])
+    if name.startswith("down"):
+        n, cin, cout = 256 >> (k - 1), UNET[k - 1], UNET[k]
+        ep = RELU if k == 8 else (LEAKY if k == 1 else LEAKY_BIAS)
+        shapes = [(UB, n, n, cin), (4, 4, cin, cout)]
+        fn = lambda x, w, b=None: ops.dconv_forward(
+            x, w, dilation=(1, 1), bias=b, epilogue=ep, **K4S2)
+        return fn, shapes + ([(cout,)] if ep.bias else [])
+    o = 256 >> k
+    cin = UNET[8] if k == 8 else 2 * UNET[k]
+    cout = 3 if k == 1 else UNET[k - 1]
+    ep = Epilogue(activation="tanh" if k == 1 else "relu", bias=True)
+    fn = lambda dy, w, b: ops.tconv_phase(dy, w, n_out=(2 * o, 2 * o),
+                                          bias=b, epilogue=ep, **K4S2)
+    return fn, [(UB, o, o, cin), (4, 4, cout, cin), (cout,)]
+
+
+@pytest.mark.parametrize("name", [f"down{k}" for k in range(1, 9)]
+                         + [f"up{k}" for k in range(8, 0, -1)])
+def test_unet256_layer_compiles_for_v5e(name, one_chip, compiled_mode):
+    fn, shapes = _unet_layer(name)
+    args = [jax.ShapeDtypeStruct(s, jnp.float32, sharding=one_chip)
+            for s in shapes]
+    _compile(fn, *args)
+
+
 def test_implicit_gemm_excluded_past_unroll_cap():
     """Compiled implicit-GEMM needs every tap unrolled; past the cap the
     planner never picks it, whatever strategy is asked for."""
@@ -119,7 +156,12 @@ def test_implicit_gemm_excluded_past_unroll_cap():
         assert picked == "phase", strategy
 
 
-def test_gan_train_step_compiles_for_v5e(one_chip, compiled_mode):
+@pytest.mark.parametrize("z_dim,base,batch", [
+    (64, 64, B),
+    (128, 256, 64),    # dcgan32's widths: the discriminator's 256-wide
+])                     # strided conv compiles once packed
+def test_gan_train_step_compiles_for_v5e(one_chip, compiled_mode, z_dim,
+                                         base, batch):
     """One whole guarded pallas GAN step (G and D forward and backward):
     every pallas_call of its jaxpr is compiled, none interpreted, and
     lowers to a tpu_custom_call."""
@@ -128,13 +170,13 @@ def test_gan_train_step_compiles_for_v5e(one_chip, compiled_mode):
     from repro.train.conv_trainer import ConvTrainer, ConvTrainerConfig
 
     tr = ConvTrainer(ConvTrainerConfig(workload="gan", backend="pallas",
-                                       z_dim=64, base=64, image=32,
-                                       batch=B))
+                                       z_dim=z_dim, base=base, image=32,
+                                       batch=batch))
     put = lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
                                          sharding=one_chip)
     state = jax.tree.map(put, jax.eval_shape(tr.init_state))
-    data = (put(jax.ShapeDtypeStruct((B, 64), jnp.float32)),
-            put(jax.ShapeDtypeStruct((B, 32, 32, 3), jnp.float32)))
+    data = (put(jax.ShapeDtypeStruct((batch, z_dim), jnp.float32)),
+            put(jax.ShapeDtypeStruct((batch, 32, 32, 3), jnp.float32)))
     lr = put(jax.ShapeDtypeStruct((), jnp.float32))
     step = tr.build_step(guarded=True)
     calls = [e for e in walk_eqns(jax.make_jaxpr(step)(state, data,
